@@ -3,11 +3,15 @@
 Per (arch × shape) cell, from the trip-count-corrected HLO analysis of the
 single-pod program:
 
-  compute term    = dot_FLOPs / peak_FLOPs          (197 TFLOP/s bf16/chip)
-  memory term     = traffic_bytes / HBM_bw          (819 GB/s/chip)
-  collective term = collective_bytes / link_bw      (50 GB/s/link/chip)
+  compute term    = dot_FLOPs / peak_FLOPs
+  memory term     = traffic_bytes / HBM_bw
+  collective term = collective_bytes / link_bw
 
-(all per-device — the HLO is the SPMD program).  Also derives
+(all per-device — the HLO is the SPMD program), with the published peaks
+of one TPU v5e chip, for which the dry-run compiles its meshes.  These are
+estimates from HLO compiled on the CPU: nothing here reads a device or a
+device trace, so the peaks never follow the chip a process runs on.  Also
+derives
 MODEL_FLOPS = 6·N_active·D (train) / 2·N_active·D (prefill/decode) and the
 useful-compute ratio MODEL/HLO-dot (catches remat + masked-attention +
 padding waste), plus roofline_frac = ideal-model-compute-time over the
@@ -34,7 +38,9 @@ from repro.configs import SHAPES, get_config
 from .common import Row
 from .kernel_microbench import attn_reclaimed_frac
 
-PEAK_FLOPS = 197e12          # TFLOP/s bf16 per v5e chip
+# Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU
+# v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI over 4 links.
+PEAK_FLOPS = 197e12          # FLOP/s bf16 per chip
 HBM_BW = 819e9               # B/s per chip
 LINK_BW = 50e9               # B/s per link (ICI)
 

@@ -76,6 +76,8 @@ def run(budget: str = "quick"):
     --fake-devices or on real hardware)."""
     import jax
 
+    from repro.launch.mesh import make_mesh
+
     steps = 4 if budget == "quick" else 16
     batch, seq = 8, 32
     rows = []
@@ -84,10 +86,10 @@ def run(budget: str = "quick"):
         rows.append(row)
     if len(jax.devices()) >= 8:
         for qname in PRESETS:
-            mesh = jax.make_mesh((4, 2), ("data", "model"))
+            mesh = make_mesh((4, 2), ("data", "model"))
             row, _ = _cell(mesh, qname, "d4m2", steps, batch, seq)
             rows.append(row)
-        pod = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        pod = make_mesh((2, 2, 2), ("pod", "data", "model"))
         row, _ = _cell(pod, "mxfp8_e4m3", "d2m2p2", steps, batch, seq)
         rows.append(row)
         row, _ = _cell(pod, "mxfp8_e4m3", "d2m2p2.mx", steps, batch, seq,
@@ -101,6 +103,8 @@ def _smoke() -> int:
     up to cross-device reduction order."""
     import jax
     import numpy as np
+
+    from repro.launch.mesh import make_mesh
 
     from .common import emit
 
@@ -127,11 +131,11 @@ def _smoke() -> int:
         refs[qname] = losses
         ok &= check(row.name, losses)
     for qname in PRESETS:
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         row, losses = _cell(mesh, qname, "d4m2", steps, batch, seq)
         rows.append(row)
         ok &= check(row.name, losses, refs[qname])
-    pod = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    pod = make_mesh((2, 2, 2), ("pod", "data", "model"))
     row, losses = _cell(pod, "mxfp8_e4m3", "d2m2p2.mx", steps, batch, seq,
                         pod_compression="e4m3", grad_accum=2)
     rows.append(row)

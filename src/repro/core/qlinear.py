@@ -29,7 +29,7 @@ contraction in the codebase, dispatching on ``kind``:
                  a precomputed (BH,S) validity mask (ring-buffer or global
                  cache semantics live in the mask).
   "attn_decode_paged"
-                 the same Tq=1 shape against (N, ps, H, ·) page pools: rhs
+                 the same Tq=1 shape against (N, H, ps, ·) page pools: rhs
                  is the (k_pool, v_pool) pair, ``pages`` the (B, P) int32
                  page table, and ``valid`` a (B, P*ps) per-view mask.  The
                  fused path scalar-prefetches the page table so the gather

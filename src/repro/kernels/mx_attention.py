@@ -386,14 +386,14 @@ def _mx_attn_decode_paged_kernel(ptc_ref, q_ref, k_ref, v_ref, msk_ref,
                                  fmt: Optional[ElementFormat], block: int,
                                  scale: float, ps: int, n_pages: int):
     """Grid (BH, P): the page dimension is innermost, so each step copies
-    one gathered page (the BlockSpec index map did the page-table lookup)
-    into the VMEM scratch slab; the last page step runs the exact slab
-    decode body on the assembled (S_view, ·) scratch — bitwise equal to
-    gathering on the host and calling the slab kernel."""
+    one gathered page of one kv head (the BlockSpec index map did the
+    page-table lookup) into the VMEM scratch slab; the last page step runs
+    the exact slab decode body on the assembled (S_view, ·) scratch —
+    bitwise equal to gathering on the host and calling the slab kernel."""
     del ptc_ref  # consumed by the BlockSpec index maps
     p = pl.program_id(1)
-    k_scr[pl.ds(p * ps, ps), :] = k_ref[0, :, 0, :].astype(jnp.float32)
-    v_scr[pl.ds(p * ps, ps), :] = v_ref[0, :, 0, :].astype(jnp.float32)
+    k_scr[pl.ds(p * ps, ps), :] = k_ref[0, 0].astype(jnp.float32)
+    v_scr[pl.ds(p * ps, ps), :] = v_ref[0, 0].astype(jnp.float32)
 
     @pl.when(p == n_pages - 1)
     def _finish():
@@ -411,32 +411,34 @@ def mx_attn_decode_paged_pallas(q: jax.Array, k_pool: jax.Array,
                                 block: int = MX_BLOCK,
                                 interpret: bool = False) -> jax.Array:
     """Paged decode: q (BH, G, d) with BH = B * H against page pools
-    k_pool/v_pool (N, ps, H, ·) through a (B, P) page table.
+    k_pool/v_pool (N, H, ps, ·) through a (B, P) page table.
 
     The page table rides in as a scalar-prefetch operand, so the k/v
     BlockSpec index maps resolve physical pages *before* the DMA — the
-    kernel itself never indexes HBM.  valid: (B, P*ps) bool per view
-    position (unallocated pages are clamped to page 0 by the gather and
-    masked here, exactly like the ref oracle)."""
+    kernel itself never indexes HBM.  Head-major pools make each DMA one
+    (ps, d) tile of one head, a legal (8, 128)-tiled block, and keep the
+    VMEM scratch at one head's (S_view, d) view.  valid: (B, P*ps) bool
+    per view position (unallocated pages are clamped to page 0 by the
+    gather and masked here, exactly like the ref oracle)."""
     BH, G, d = q.shape
     B, P = page_table.shape
     H = BH // B
-    N, ps, _, dk = k_pool.shape
+    N, _, ps, dk = k_pool.shape
     dv_ = v_pool.shape[-1]
     S_view = P * ps
     scale = 1.0 / math.sqrt(d)
     ptc = jnp.clip(page_table, 0, N - 1).astype(jnp.int32)
-    msk = jnp.repeat(valid, H, axis=0).astype(jnp.int32)[:, None, :]
+    msk = valid.astype(jnp.int32)[:, None, :]              # (B, 1, S_view)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(BH, P),
         in_specs=[
             pl.BlockSpec((1, G, d), lambda bh, p, pt: (bh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, dk),
-                         lambda bh, p, pt: (pt[bh // H, p], 0, bh % H, 0)),
-            pl.BlockSpec((1, ps, 1, dv_),
-                         lambda bh, p, pt: (pt[bh // H, p], 0, bh % H, 0)),
-            pl.BlockSpec((1, 1, S_view), lambda bh, p, pt: (bh, 0, 0)),
+            pl.BlockSpec((1, 1, ps, dk),
+                         lambda bh, p, pt: (pt[bh // H, p], bh % H, 0, 0)),
+            pl.BlockSpec((1, 1, ps, dv_),
+                         lambda bh, p, pt: (pt[bh // H, p], bh % H, 0, 0)),
+            pl.BlockSpec((1, 1, S_view), lambda bh, p, pt: (bh // H, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, G, dv_), lambda bh, p, pt: (bh, 0, 0)),
         scratch_shapes=[pltpu.VMEM((S_view, dk), jnp.float32),

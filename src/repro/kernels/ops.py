@@ -152,7 +152,7 @@ def mx_attention_decode_paged(q: jax.Array, k_pool: jax.Array,
                               fmt: Optional[ElementFormat],
                               block: int = MX_BLOCK,
                               scale_mode: str = "floor") -> jax.Array:
-    """Kernel-backed paged decode: q (BH,G,d) against (N,ps,H,·) page pools
+    """Kernel-backed paged decode: q (BH,G,d) against (N,H,ps,·) page pools
     through a (B,P) page table with a (B, P*ps) per-view validity mask.
 
     The Pallas path scalar-prefetches the page table so the gather happens
@@ -160,7 +160,7 @@ def mx_attention_decode_paged(q: jax.Array, k_pool: jax.Array,
     not MX-block multiples, non-floor scales) fall back to the gather+slab
     jnp oracle — same numerics either way."""
     d = q.shape[-1]
-    ps = k_pool.shape[1]
+    ps = k_pool.shape[2]
     S_view = page_table.shape[1] * ps
     if ps % block or not _attn_kernel_ok(fmt, scale_mode, d, S_view, block):
         return ref.mx_attention_decode_paged_ref(

@@ -14,6 +14,7 @@ triangle the roofline flags without waiting for the fused kernel.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -60,20 +61,29 @@ def mx_matmul_dgrad_ref(dy: jax.Array, w: jax.Array,
     dgrad contraction axis).  dy: (..., N); w: (K, N) in forward layout."""
     dyq = quantize_mx(dy, fmt_g, axis=-1, block=block)
     wq = quantize_mx(w, fmt_w, axis=1, block=block)
-    return jnp.matmul(dyq, wq.T, preferred_element_type=jnp.float32
-                      ).astype(dy.dtype)
+    # Contract the shared N axis in place, as the kernel does: XLA:CPU
+    # accumulates a dot on a materialized transpose in another order.
+    return jax.lax.dot_general(dyq, wq, (((dyq.ndim - 1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32
+                               ).astype(dy.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("fmt_a", "fmt_g", "block"))
 def mx_matmul_wgrad_ref(x: jax.Array, dy: jax.Array,
                         fmt_a: Optional[ElementFormat],
                         fmt_g: Optional[ElementFormat],
                         block: int = MX_BLOCK) -> jax.Array:
-    """wgrad oracle: ``dW = Q(x)^T @ Q(dy)`` with MX blocks along T (the
-    token/contraction axis).  x: (T, K); dy: (T, N)."""
-    xq = quantize_mx(x, fmt_a, axis=0, block=block)
-    dyq = quantize_mx(dy, fmt_g, axis=0, block=block)
-    return jnp.matmul(xq.T, dyq, preferred_element_type=jnp.float32
-                      ).astype(x.dtype)
+    """wgrad oracle: ``dW = Q(x^T) @ Q(dy^T)^T`` with MX blocks along T
+    (the token/contraction axis).  x: (T, K); dy: (T, N).
+
+    Written, and jitted, so that T is the minor axis of both dot operands,
+    as it is for the kernel's in-register transposed tiles: XLA:CPU's
+    accumulation order follows the contraction's layout."""
+    xq = quantize_mx(x.T, fmt_a, axis=-1, block=block)
+    dyq = quantize_mx(dy.T, fmt_g, axis=-1, block=block)
+    return jax.lax.dot_general(xq, dyq, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32
+                               ).astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -371,16 +381,16 @@ def gather_pages(pool: jax.Array, page_table: jax.Array,
                  n_kv: int) -> jax.Array:
     """Assemble the folded (B*H, P*ps, d) contiguous view of a page pool.
 
-    pool: (N, ps, H, d) global page pool (H = n_kv heads); page_table:
+    pool: (N, H, ps, d) global page pool (H = n_kv heads); page_table:
     (B, P) int32, negatives = unallocated (the gather clamps them to page
     0 — callers mask those view positions out via ``valid``).  Logical
     position ``t`` of request ``b`` lives at view position ``t`` exactly:
     page ``t // ps``, offset ``t % ps``."""
     B, P = page_table.shape
-    N, ps, H, d = pool.shape
+    N, H, ps, d = pool.shape
     ptc = jnp.clip(page_table, 0, N - 1)
-    g = pool[ptc]                                  # (B, P, ps, H, d)
-    return g.transpose(0, 3, 1, 2, 4).reshape(B * H, P * ps, d)
+    g = pool[ptc]                                  # (B, P, H, ps, d)
+    return g.transpose(0, 2, 1, 3, 4).reshape(B * H, P * ps, d)
 
 
 def mx_attention_decode_paged_ref(q: jax.Array, k_pool: jax.Array,
@@ -394,7 +404,7 @@ def mx_attention_decode_paged_ref(q: jax.Array, k_pool: jax.Array,
     gather, so paged output is bitwise equal to slab output whenever the
     gathered view holds the same values.
 
-    q: (BH, G, d) with BH = B * n_kv; k_pool/v_pool: (N, ps, H, dk/dv);
+    q: (BH, G, d) with BH = B * n_kv; k_pool/v_pool: (N, H, ps, dk/dv);
     page_table: (B, P) int32; valid: (B, P*ps) bool per *view* position
     (allocated page AND logical position <= pos)."""
     B = page_table.shape[0]

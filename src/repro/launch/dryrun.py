@@ -37,14 +37,10 @@ import traceback
 import jax
 import jax.numpy as jnp
 
-# Persistent compilation cache: §Perf iterations re-lower unchanged cells
-# for free.
-jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
-
 from repro.configs import SHAPES, get_config, input_specs, list_archs, \
     supported
 from repro.core import preset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import make_prefill_step, make_serve_step, \
@@ -186,6 +182,10 @@ def main():
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
+    # Persistent compilation cache: §Perf iterations re-lower unchanged
+    # cells for free.
+    enable_compile_cache()
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
     archs = [a for a in list_archs() if a != "olmo-paper"] \
         if args.arch == "all" else args.arch.split(",")
     shapes = list(SHAPES) if args.shape == "all" else args.shape.split(",")

@@ -65,10 +65,12 @@ def main(argv=None) -> int:
             f"--fake-devices {args.fake_devices} had no effect "
             f"({jax.device_count()} devices): jax was already initialized")
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import mesh_from_flag
     from repro.sweep import (RunDB, SweepSpec, aggregate, format_table,
                              get_sweep_spec, run_sweep)
 
+    enable_compile_cache()
     if args.preset:
         spec = get_sweep_spec(args.preset, args.budget)
     else:

@@ -22,7 +22,7 @@ import os
 import sys
 
 
-def _parse_args(argv=None):
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-paper")
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
@@ -69,8 +69,9 @@ def _parse_args(argv=None):
     return ap.parse_args(argv)
 
 
-def main(argv=None):
-    args = _parse_args(argv)
+def build_trainer(args):
+    """The Trainer ``main`` runs, built from parsed ``args`` (see
+    :func:`parse_args`); the compilation cache is on before it returns."""
     if args.fake_devices:
         # jax may already be *imported* (package __init__), but XLA_FLAGS
         # is only read when the backend initializes — which is lazy, so
@@ -86,16 +87,18 @@ def main(argv=None):
         raise RuntimeError(
             f"--fake-devices {args.fake_devices} had no effect "
             f"({jax.device_count()} devices): the jax backend was already "
-            "initialized before main() ran")
+            "initialized before the trainer was built")
 
     from repro.configs import get_config
     from repro.core import preset
     from repro.data.synthetic import lm_input_arrays
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import mesh_from_flag
     from repro.models import lm_init, lm_loss
     from repro.optim import AdamWConfig
     from repro.train import Trainer, TrainerConfig
 
+    enable_compile_cache()
     cfg = get_config(args.arch, args.variant)
     qcfg = preset(args.precision)
     mesh = mesh_from_flag(args.mesh)
@@ -113,12 +116,17 @@ def main(argv=None):
                          pod_compression=args.pod_compress,
                          guard=args.guard,
                          guard_probe_every=args.guard_probe_every)
-    trainer = Trainer(
+    return Trainer(
         loss_fn=lambda p, b, q: lm_loss(p, b, cfg, q),
         params=params, qcfg=qcfg,
         batch_fn=lambda step: lm_input_arrays(step, cfg, args.batch,
                                               args.seq, args.seed),
         opt_cfg=AdamWConfig(), tcfg=tcfg, mesh=mesh)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    trainer = build_trainer(args)
     if args.resume and trainer.restore():
         # restore() adopts the checkpoint's recorded qcfg/recovery count,
         # so a resume after a mid-run intervention keeps the intervention.

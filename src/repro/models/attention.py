@@ -230,7 +230,7 @@ def attention_decode_paged(p, x, cache, *, qcfg: QuantConfig, n_heads: int,
                            rope_theta: float = 1e4, use_rope: bool = True):
     """One-token decode against (k, v) page pools.
 
-    x: (B, 1, D); cache: {"k": (N, ps, Hkv, d), "v": ...} — global pools
+    x: (B, 1, D); cache: {"k": (N, Hkv, ps, d), "v": ...} — global pools
     shared by every row through the (B, P) ``page_table`` (physical page of
     logical page ``t // ps``; -1 = unallocated).  The new token scatters
     into its row's current tail page; dead rows (all -1 tables) resolve to
@@ -240,7 +240,7 @@ def attention_decode_paged(p, x, cache, *, qcfg: QuantConfig, n_heads: int,
     gather+slab oracle otherwise (bitwise-identical numerics).
     """
     B = x.shape[0]
-    N, ps = cache["k"].shape[0], cache["k"].shape[1]
+    N, ps = cache["k"].shape[0], cache["k"].shape[2]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
     q, k_new, v_new = _project_qkv(p, x, x, qcfg, n_heads, n_kv, d_head,
@@ -252,10 +252,10 @@ def attention_decode_paged(p, x, cache, *, qcfg: QuantConfig, n_heads: int,
     # range (dropped), never at page -1 == page N-1.
     phys = jnp.where(phys < 0, N, phys)
     off = pos % ps
-    k = cache["k"].at[phys, off].set(k_new[:, 0].astype(cache["k"].dtype),
-                                     mode="drop")
-    v = cache["v"].at[phys, off].set(v_new[:, 0].astype(cache["v"].dtype),
-                                     mode="drop")
+    k = cache["k"].at[phys, :, off].set(
+        k_new[:, 0].astype(cache["k"].dtype), mode="drop")
+    v = cache["v"].at[phys, :, off].set(
+        v_new[:, 0].astype(cache["v"].dtype), mode="drop")
     G = n_heads // n_kv
     qf = q[:, 0].reshape(B * n_kv, G, d_head)
     valid = paged_valid_mask(page_table, pos, ps)

@@ -524,13 +524,15 @@ def kind_paged(kind: str, cfg: LMConfig) -> bool:
 
 def _paged_cache_init(kind: str, cfg: LMConfig, n_pages: int,
                       page_size: int):
-    """Page-pool leaves for one paged block: (N, ps, ...) global pools
-    shared across batch rows through the engine's page table."""
+    """Page-pool leaves for one paged block: global pools shared across
+    batch rows through the engine's page table — head-major (N, Hkv, ps, d)
+    K/V pools (one (ps, d) tile per head and page, what the paged decode
+    kernel DMAs), (N, ps, ·) MLA latent pools."""
     dt = COMPUTE_DTYPE
     if cfg.mla:
         return {"ckv": jnp.zeros((n_pages, page_size, cfg.kv_lora), dt),
                 "kr": jnp.zeros((n_pages, page_size, cfg.rope_dim), dt)}
-    shp = (n_pages, page_size, cfg.n_kv_heads, cfg.d_head)
+    shp = (n_pages, cfg.n_kv_heads, page_size, cfg.d_head)
     return {"k": jnp.zeros(shp, dt), "v": jnp.zeros(shp, dt)}
 
 

@@ -92,6 +92,11 @@ class SegmentFn:
         self.calls += 1
         return self._jit(*args, **kwargs)
 
+    def lower(self, *args, **kwargs):
+        """``jax.jit(...).lower``: the program a call with these arguments
+        runs, for inspection (``.compile().as_text()``); runs nothing."""
+        return self._jit.lower(*args, **kwargs)
+
     # ---- accounting --------------------------------------------------------
     @property
     def n_traces(self) -> int:

@@ -575,7 +575,8 @@ class PagedServeEngine(ServeEngine):
         pools = tuple(leaves[i] for i in self._paged_idx)
         prior_ids = self._row_ids(job.pages, 0, start // ps)
         prior = jax.tree_util.tree_unflatten(
-            self._treedef, list(gather_prior(pools, jnp.asarray(prior_ids))))
+            self._treedef, list(gather_prior(pools, jnp.asarray(prior_ids),
+                                             self._rules)))
         logits, chunk_kv = _prefill_chunk(
             self.params, jnp.asarray(toks)[None], prior, start, self.cfg, qc,
             jnp.asarray([real - 1], jnp.int32), kv_mask)

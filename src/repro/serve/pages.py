@@ -212,16 +212,22 @@ def zero_pages(pools: Tuple[jax.Array, ...], ids: jax.Array):
     return tuple(z(p) for p in pools)
 
 
-@jax.jit
-def gather_prior(pools: Tuple[jax.Array, ...], ids: jax.Array):
+@partial(jax.jit, static_argnames=("rules",))
+def gather_prior(pools: Tuple[jax.Array, ...], ids: jax.Array,
+                 rules: Tuple[str, ...]):
     """Assemble the contiguous (n_rep, 1, n*ps, ...) prefix view of the
     first ``n`` logical pages (``ids``: (n,) physical ids, all valid) —
-    what a prefill chunk attends to as its prior K/V."""
-    def g(p):
-        n_rep, N, ps = p.shape[:3]
-        gp = p[:, jnp.clip(ids, 0, N - 1)]           # (n_rep, n, ps, ...)
-        return gp.reshape((n_rep, 1, ids.shape[0] * ps) + p.shape[3:])
-    return tuple(g(p) for p in pools)
+    what a prefill chunk attends to as its prior K/V.  ``rules`` as in
+    :func:`write_chunk_pages`: "k"/"v" pools are head-major and come back
+    position-major, like the chunk K/V they extend."""
+    def g(p, rule):
+        n_rep, N = p.shape[:2]
+        gp = p[:, jnp.clip(ids, 0, N - 1)]           # (n_rep, n, ...)
+        if rule in ("k", "v"):
+            gp = jnp.swapaxes(gp, 2, 3)              # (n_rep, n, ps, H, d)
+        return gp.reshape((n_rep, 1, ids.shape[0] * gp.shape[2])
+                          + gp.shape[3:])
+    return tuple(g(p, r) for p, r in zip(pools, rules))
 
 
 @partial(jax.jit, donate_argnums=(0,),
@@ -234,17 +240,19 @@ def write_chunk_pages(pools: Tuple[jax.Array, ...],
     ps) into physical pages ``ids`` (pad with >= N to drop), MX-quantizing
     sealed pages at rest.
 
-    ``rules`` names each leaf's at-rest treatment: "k" quantizes along the
+    ``rules`` names each leaf's layout and at-rest treatment.  "k" and "v"
+    are head-major (n_rep, N, H, ps, d) K/V pools; "k" quantizes along the
     head dim (per-position blocks — always safe), "v" along the in-page
     position axis but only for the first ``n_sealed`` fully-real pages (a
     partial page's block max would shift as later tokens arrive, breaking
-    Q∘Q idempotence), "raw" stores bf16 (MLA latents).  Because the decode
+    Q∘Q idempotence).  "raw" leaves are position-major (n_rep, N, ps, ·)
+    and stored bf16 (MLA latents).  Because the decode
     oracle quantizes with the same axes and page-aligned blocks, at-rest
     quantization is bitwise-invisible to attention output."""
     n_pg = ids.shape[0]
 
     def w(pool, ck, rule):
-        n_rep, N, ps = pool.shape[:3]
+        n_rep, ps = pool.shape[0], ck.shape[2] // n_pg
         pages = ck.reshape((n_rep, n_pg, ps) + ck.shape[3:])
         if fmt is not None and rule in ("k", "v"):
             axis = -1 if rule == "k" else 2
@@ -254,6 +262,8 @@ def write_chunk_pages(pools: Tuple[jax.Array, ...],
             sh = (1, n_pg) + (1,) * (pages.ndim - 2)
             pages = jnp.where(sealed.reshape(sh), q,
                               pages.astype(jnp.float32))
+        if rule in ("k", "v"):
+            pages = jnp.swapaxes(pages, 2, 3)        # head-major, as the pool
         return pool.at[:, ids].set(pages.astype(pool.dtype), mode="drop")
 
     return tuple(w(p, c, r) for p, c, r in zip(pools, chunks, rules))

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import warnings
 from typing import Any, Callable, Dict, List, Optional
 
@@ -66,7 +67,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import (QuantConfig, SpikeDetector, apply_intervention,
-                        fused_gemms_enabled, get_format)
+                        fused_gemms_enabled, get_format, use_fused_gemms)
 from repro.optim import AdamWConfig, adamw_init, adamw_update, warmup_cosine
 from repro.runtime import (Journal, MemoryLedger, MetricsWindow, SegmentFn,
                            SegmentTracker, checkpoint_meta,
@@ -114,6 +115,14 @@ def _microbatched(batch, n: int, what: str = "grad_accum"):
                 f"{what}={n} does not divide batch dim {x.shape[0]}")
         return x.reshape((n, x.shape[0] // n) + x.shape[1:])
     return jax.tree.map(one, batch)
+
+
+def _kernels_partitionable(mesh) -> bool:
+    """GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so a step
+    sharded over several devices traces the jnp emulation of every
+    contraction instead: the same MX numerics (tests pin fused ==
+    emulated), without the fused kernels."""
+    return mesh is None or mesh.size == 1
 
 
 def make_train_step(loss_fn: Callable, opt_cfg: AdamWConfig,
@@ -167,11 +176,6 @@ def make_train_step(loss_fn: Callable, opt_cfg: AdamWConfig,
     if pod:
         from repro.parallel import compressed_psum, compression_error_terms
         npod = mesh.shape["pod"]
-        auto = frozenset(a for a in mesh.axis_names if a != "pod")
-        try:
-            from jax import shard_map  # jax >= 0.5
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
 
         def exchange(gs):
             # shard_map body, manual over "pod" only: each pod holds its
@@ -215,8 +219,9 @@ def make_train_step(loss_fn: Callable, opt_cfg: AdamWConfig,
                 jax.lax.with_sharding_constraint(
                     g, NamedSharding(mesh, P("pod", *s)))
                 for g, s in zip(flat, specs)])
-            f = shard_map(exchange, mesh=mesh, in_specs=(P("pod"),),
-                          out_specs=(P(), P()), check_rep=False, auto=auto)
+            f = jax.shard_map(exchange, mesh=mesh, in_specs=(P("pod"),),
+                              out_specs=(P(), P()), axis_names={"pod"},
+                              check_vma=False)
             grads, err = f(grads)
             loss = jnp.mean(loss)
             metrics = jax.tree.map(lambda m: jnp.mean(m, axis=0), metrics)
@@ -271,6 +276,14 @@ def make_train_step(loss_fn: Callable, opt_cfg: AdamWConfig,
                                         monitor_init(monitors))
         shapes = lambda pl, ol, bl, rep: (
             ((pl, ol, mrep(rep), bl, rep), (pl, ol, mrep(rep), rep)))
+
+    if not _kernels_partitionable(mesh):
+        kernel_step = step_fn
+
+        @functools.wraps(kernel_step)
+        def step_fn(*args):
+            with use_fused_gemms(False):
+                return kernel_step(*args)
 
     if mesh is None:
         return SegmentFn(step_fn, static_argnums=static,
@@ -524,6 +537,26 @@ class Trainer:
                 return f"spike@step{s}: loss={loss:.4g}", i + 1
         return None, len(pending)
 
+    def _mesh_context(self) -> contextlib.ExitStack:
+        ctx = contextlib.ExitStack()
+        if self.mesh is not None:
+            from repro.parallel.sharding import activation_sharding
+            ctx.enter_context(self.mesh)
+            ctx.enter_context(activation_sharding(self.mesh))
+        return ctx
+
+    def lower_step(self):
+        """Lower, without running, the step the next ``run`` executes
+        (``.compile().as_text()`` shows which kernels it calls)."""
+        batch = self.batch_fn(self.step)
+        if self._bshard is not None:
+            batch = jax.device_put(batch, self._bshard)
+        carry = (self.params, self.opt_state) if self._mcfg is None else (
+            self.params, self.opt_state, self._mstate)
+        with self._mesh_context():
+            return self._step_fn.lower(*carry, batch, jnp.asarray(self.step),
+                                       self.qcfg)
+
     # ---- main loop ---------------------------------------------------------
     def run(self, n_steps: Optional[int] = None):
         if self._fused_gemms is None:
@@ -531,7 +564,8 @@ class Trainer:
             # _step_fn's jit cache at first trace, so later toggles of
             # use_fused_gemms would not change the executing path.  Recorded
             # so run reports can attribute throughput.
-            self._fused_gemms = fused_gemms_enabled()
+            self._fused_gemms = (fused_gemms_enabled()
+                                 and _kernels_partitionable(self.mesh))
         if not self.events or self.events[-1].get("event") != "run_start":
             self.events.append({"step": self.step, "event": "run_start",
                                 "fused_gemms": self._fused_gemms,
@@ -547,11 +581,7 @@ class Trainer:
         log_every = max(self.tcfg.log_every, 1)
         window = MetricsWindow()
         aborted = False
-        with contextlib.ExitStack() as ctx:
-            if self.mesh is not None:
-                from repro.parallel.sharding import activation_sharding
-                ctx.enter_context(self.mesh)
-                ctx.enter_context(activation_sharding(self.mesh))
+        with self._mesh_context():
             window.reset_clock()
             while self.step < end:
                 batch = self.batch_fn(self.step)

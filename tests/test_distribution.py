@@ -59,6 +59,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.core import preset
     from repro.data.synthetic import lm_input_arrays
+    from repro.launch.mesh import make_mesh
     from repro.models import lm_init, lm_loss
     from repro.parallel import batch_pspecs, param_pspecs, shardings_like
     from repro.parallel.sharding import activation_sharding
@@ -71,7 +72,7 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     # single-device reference
     loss_ref, _ = jax.jit(lambda p, b: lm_loss(p, b, cfg, qcfg))(params, batch)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     psh = shardings_like(param_pspecs(params), mesh)
     bsh = shardings_like(batch_pspecs(batch, mesh), mesh)
     params_s = jax.device_put(params, psh)
@@ -106,16 +107,13 @@ _COMPRESS_SCRIPT = textwrap.dedent("""
     from functools import partial
     from jax.sharding import PartitionSpec as P
     from repro.core import E4M3
+    from repro.launch.mesh import make_mesh
     from repro.parallel import compressed_psum
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # jax < 0.5 keeps it under experimental
-        from jax.experimental.shard_map import shard_map
-
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     x = jax.random.normal(jax.random.PRNGKey(0), (4, 8, 64))
 
-    @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
     def f(xs):
         return compressed_psum({"g": xs[0]}, "pod", E4M3)["g"][None]
 
@@ -143,6 +141,7 @@ _TRAINER_PARITY_SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.core import preset
     from repro.data.synthetic import lm_input_arrays
+    from repro.launch.mesh import make_mesh
     from repro.models import lm_init, lm_loss
     from repro.train import Trainer, TrainerConfig
 
@@ -160,11 +159,11 @@ _TRAINER_PARITY_SCRIPT = textwrap.dedent("""
                 "comp_err": [h.get("compression_error") for h in hist]}
 
     out = {}
-    pod = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    pod = make_mesh((2, 2, 2), ("pod", "data", "model"))
     for qname in ("bf16", "mxfp8_e4m3"):
         out[qname] = {
             "ref": run(None, qname),
-            "fsdp": run(jax.make_mesh((4, 2), ("data", "model")), qname),
+            "fsdp": run(make_mesh((4, 2), ("data", "model")), qname),
             "pod": run(pod, qname),
         }
     out["mxfp8_e4m3"]["podmx"] = run(pod, "mxfp8_e4m3",
@@ -210,6 +209,7 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.core import preset
     from repro.data.synthetic import lm_input_arrays
+    from repro.launch.mesh import make_mesh
     from repro.models import lm_init, lm_loss
     from repro.train import Trainer, TrainerConfig
 
@@ -226,13 +226,13 @@ _ELASTIC_SCRIPT = textwrap.dedent("""
                        tcfg=tcfg, mesh=mesh)
 
     # write on a (4,2) FSDP+TP mesh
-    t1 = make(jax.make_mesh((4, 2), ("data", "model")))
+    t1 = make(make_mesh((4, 2), ("data", "model")))
     t1.run(4)
     t1._ckptr.wait()
 
     out = {}
     # restore onto: pod mesh, single device — both must resume at step 4
-    for tag, mesh in (("pod", jax.make_mesh((2, 2, 2),
+    for tag, mesh in (("pod", make_mesh((2, 2, 2),
                                             ("pod", "data", "model"))),
                       ("1dev", None)):
         t2 = make(mesh)

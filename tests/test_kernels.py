@@ -36,6 +36,17 @@ def test_quant_kernel_axis0(fmt):
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
 
+def test_quant_kernel_wide_rows_shrink_the_row_tile():
+    """Rows too wide for a 256-row block in VMEM get a shorter row tile
+    (here 16 rows, so 24 rows pad to two tiles); same bits."""
+    from repro.kernels.mx_quant import _TILE_ELEMS
+    k = _TILE_ELEMS // 16
+    rng = np.random.RandomState(7)   # leave RNG's stream to the other tests
+    x = jnp.asarray(rng.randn(24, k).astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(mx_quantize(x, E4M3)),
+                                  np.asarray(mx_quantize_ref(x, E4M3)))
+
+
 @pytest.mark.parametrize("mkn", [(32, 32, 32), (64, 128, 32), (128, 256, 64),
                                  (16, 96, 48), (100, 160, 72)], ids=str)
 @pytest.mark.parametrize("fa,fb", [(E4M3, E4M3), (E5M2, E4M3), (None, E2M3),
@@ -160,10 +171,49 @@ def test_kernels_compiled_on_tpu_match_ref():
         np.asarray(mx_matmul_dgrad(dy, w, E5M2, E4M3)),
         np.asarray(mx_matmul_dgrad_ref(dy, w, E5M2, E4M3)),
         rtol=1e-5, atol=1e-4)
+    # wgrad contracts the token axis: x (512, 384) against dy^T (512, 256)
     np.testing.assert_allclose(
-        np.asarray(mx_matmul_wgrad(x, dy, E4M3, E5M2)),
-        np.asarray(mx_matmul_wgrad_ref(x, dy, E4M3, E5M2)),
+        np.asarray(mx_matmul_wgrad(x, dy.T, E4M3, E5M2)),
+        np.asarray(mx_matmul_wgrad_ref(x, dy.T, E4M3, E5M2)),
         rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.tpu
+@pytest.mark.skipif(jax.default_backend() != "tpu",
+                    reason="compiled (non-interpret) kernels need a TPU")
+@pytest.mark.parametrize("fmt", FMTS, ids=lambda f: f.name)
+def test_kernels_compiled_on_tpu_match_ref_at_olmo_widths(fmt):
+    """At olmo-paper widths (4096 tokens, d_model 512, d_ff 2048, 8 heads
+    of 64, context 512) on the chip: the quantizer — whose per-32-lane
+    block max is a butterfly of lane rotations — equals the oracle
+    bitwise, so its rotations run the way interpret mode runs them; the
+    forward GEMM agrees to fp32-accumulation-order tolerance; and the
+    flash forward agrees as the any-shape property in test_properties.py
+    asks (tight logsumexp, a 1-mantissa-step budget on the output)."""
+    from repro.configs import get_config
+    from repro.kernels import mx_flash_attention, mx_flash_attention_ref
+    rng = np.random.RandomState(11)
+    for shape in [(4096, 512), (4096, 2048)]:
+        x = jnp.asarray(rng.randn(*shape).astype(np.float32) * 5
+                        ).astype(jnp.bfloat16)
+        np.testing.assert_array_equal(
+            np.asarray(mx_quantize(x, fmt), np.float32),
+            np.asarray(mx_quantize_ref(x, fmt), np.float32))
+    a = jnp.asarray(rng.randn(4096, 512).astype(np.float32))
+    b = jnp.asarray(rng.randn(512, 2048).astype(np.float32))
+    np.testing.assert_allclose(
+        np.asarray(mx_matmul(a, b, fmt, fmt)),
+        np.asarray(mx_matmul_ref(a, b, fmt, fmt)), rtol=1e-5, atol=1e-4)
+    spec = get_config("olmo-paper", "full").attn_spec()
+    q = jnp.asarray(rng.randn(16, 1, 512, 64).astype(np.float32))
+    k = jnp.asarray(rng.randn(16, 512, 64).astype(np.float32))
+    v = jnp.asarray(rng.randn(16, 512, 64).astype(np.float32))
+    o_k, l_k = mx_flash_attention(q, k, v, fmt, spec)
+    o_r, l_r = jax.jit(mx_flash_attention_ref,
+                       static_argnames=("fmt", "spec"))(q, k, v, fmt, spec)
+    o_k, l_k, o_r, l_r = (np.asarray(t) for t in (o_k, l_k, o_r, l_r))
+    np.testing.assert_allclose(l_k, l_r, rtol=3e-7, atol=1e-5)
+    assert np.linalg.norm(o_k - o_r) / np.linalg.norm(o_r) < 0.05
 
 
 # ---------------------------------------------------------------------------

@@ -293,8 +293,11 @@ def test_paged_decode_kernel_bit_identical_to_gather_plus_slab(fmt):
     rng = np.random.RandomState(9)
     B, H, G, d, ps, P, N = 2, 2, 2, 32, 32, 4, 8
     q = jnp.asarray(rng.randn(B * H, G, d).astype(np.float32))
-    k_pool = jnp.asarray(rng.randn(N, ps, H, d).astype(np.float32))
-    v_pool = jnp.asarray(rng.randn(N, ps, H, d).astype(np.float32))
+    # head-major (N, H, ps, d) pools
+    k_pool = jnp.asarray(rng.randn(N, ps, H, d).astype(np.float32)
+                         .transpose(0, 2, 1, 3))
+    v_pool = jnp.asarray(rng.randn(N, ps, H, d).astype(np.float32)
+                         .transpose(0, 2, 1, 3))
     pt = jnp.asarray([[5, 2, -1, -1], [0, 7, 3, -1]], jnp.int32)
     pos = jnp.asarray([[40], [70]])
     valid = (jnp.arange(P * ps)[None, :] <= pos) & (
