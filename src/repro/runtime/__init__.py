@@ -15,6 +15,8 @@ package:
   :func:`parse_checkpoint_meta`).
 * :mod:`~repro.runtime.memory` — :class:`MemoryLedger` device-memory
   accounting with a budget guard.
+* :mod:`~repro.runtime.spans` — :class:`Spans`, named host phases that
+  land on the profiler's clock and keep their times per step.
 * :func:`snapshot_to_serve` — a mid-training model handed to the
   serving engine on-device, no checkpoint round-trip.
 """
@@ -24,6 +26,7 @@ from .journal import (RECORD_KINDS, Journal, JsonlSink, RestoredMeta,
 from .memory import MemoryBudgetError, MemoryLedger, tree_bytes
 from .segments import (MetricsWindow, Segment, SegmentFn, SegmentTracker,
                        cache_stats, plan_segments, registry, total_traces)
+from .spans import Spans
 
 __all__ = [
     "Journal", "JsonlSink", "RECORD_KINDS", "read_jsonl", "RestoredMeta",
@@ -31,5 +34,5 @@ __all__ = [
     "SegmentFn", "Segment", "plan_segments", "SegmentTracker",
     "MetricsWindow", "registry", "cache_stats", "total_traces",
     "MemoryLedger", "MemoryBudgetError", "tree_bytes",
-    "snapshot_to_serve",
+    "snapshot_to_serve", "Spans",
 ]

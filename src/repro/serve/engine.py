@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import QuantConfig
-from repro.runtime import Journal, MemoryLedger, SegmentFn
+from repro.runtime import Journal, MemoryLedger, SegmentFn, Spans
 from repro.models import (LMConfig, block_plan, chunk_supported, init_cache,
                           init_cache_paged, lm_decode_step, lm_prefill,
                           lm_prefill_chunk, paged_leaf_mask,
@@ -181,10 +181,11 @@ class ServeEngine:
         self.ledger = MemoryLedger(name="serve")
         self.ledger.account("params", params)
         self.ledger.account("cache", self.cache)
+        # host phases of each step, on the profiler's clock (serve.*)
+        self.spans = Spans("serve")
         self.finished: Dict[int, Request] = {}
         self._next_rid = 0
         self._decode_steps = 0
-        self._decode_time = 0.0
         self._decode_tokens = 0
         self._prefill_tokens = 0
         self._prefill_time = 0.0
@@ -261,25 +262,41 @@ class ServeEngine:
         """
         finished = []
         staged = []
-        for slot, req in self.sched.admissions():
-            t0 = time.perf_counter()
-            logits, one_cache, padded = self._prefill_one(req)
-            first = self._first_token(logits, req.sampling)
-            self.cache = _insert_row(self.cache, one_cache, slot)
-            staged.append((slot, req, first, padded, t0))
-        for slot, req, first, padded, t0 in staged:
-            tok0 = int(first[0])               # realizes this admission
-            dt = time.perf_counter() - t0
-            self._prefill_tokens += int(req.prompt.size)
-            self._prefill_time += dt
-            self.events.append({"event": "prefill", "rid": req.rid,
-                                "slot": slot,
-                                "prompt_len": int(req.prompt.size),
-                                "padded_len": padded, "fused": self.fused,
-                                "time_s": dt})
-            if self.sched.place(slot, req, tok0, req.prompt.size):
-                finished.append(req)
+        with self.spans("admit"):
+            admitted = self.sched.admissions()
+        for slot, req in admitted:
+            with self.spans("prefill", tokens=int(req.prompt.size)):
+                req.prefill_t = time.perf_counter()
+                logits, one_cache, padded = self._prefill_one(req)
+                first = self._first_token(logits, req.sampling)
+                self.cache = _insert_row(self.cache, one_cache, slot)
+            staged.append((slot, req, first, padded))
+        for slot, req, first, padded in staged:
+            with self.spans("prefill.wait"):
+                tok0 = int(first[0])           # realizes this admission
+            with self.spans("place"):
+                if self._place(slot, req, tok0, req.prefill_t, padded):
+                    finished.append(req)
         return finished
+
+    def _place(self, slot: int, req: Request, tok0: int, since: float,
+               padded_len: int, **fields) -> bool:
+        """Install a prefilled request in ``slot`` and journal its prefill:
+        ``time_s`` from ``since`` to its first token, ``queue_s`` (to its
+        first chunk's dispatch) and ``ttft_s`` from its submission.
+        Returns True when the request already finished."""
+        T = int(req.prompt.size)
+        done = self.sched.place(slot, req, tok0, T)
+        dt = req.first_token_t - since
+        self._prefill_tokens += T
+        self._prefill_time += dt
+        self.events.append({"event": "prefill", "rid": req.rid,
+                            "slot": slot, "prompt_len": T,
+                            "padded_len": padded_len, "fused": self.fused,
+                            **fields, "time_s": dt,
+                            "queue_s": req.prefill_t - req.submit_t,
+                            "ttft_s": req.first_token_t - req.submit_t})
+        return done
 
     # ---- stepping ----------------------------------------------------------
     def _pre_decode(self) -> List[Request]:
@@ -303,29 +320,41 @@ class ServeEngine:
 
     def step(self) -> List[Request]:
         """Admit what fits, then advance every live slot one token.
-        Returns the requests that finished during this call."""
-        finished = self._admit()
-        finished.extend(self._pre_decode())
-        if self.sched.n_active:
-            tok, pos, temp, top_k, seeds, n_gen = self.sched.batch_arrays()
-            t0 = time.perf_counter()
-            nxt = self._decode_batch(tok, pos, temp, top_k, seeds, n_gen,
-                                     bool((self.sched.temp > 0).any()),
-                                     bool((self.sched.top_k > 0).any()))
-            nxt = np.asarray(nxt)
-            dt = time.perf_counter() - t0
+        Returns the requests that finished during this call.
+
+        Each phase is a ``serve.*`` span (``self.spans``); ``decode``
+        carries the rows decoded for a request (``live``) and for none
+        (``dead``: free, or reserved mid-prefill), and the ``*.wait``
+        phases are where the host blocks on the device."""
+        with self.spans.step():
+            finished = self._admit()
+            with self.spans("pages"):
+                finished.extend(self._pre_decode())
             n_live = self.sched.n_active
-            self._decode_steps += 1
-            self._decode_time += dt
-            self._decode_tokens += n_live
-            finished.extend(self.sched.record_step(nxt))
-        self._post_finish(finished)
-        for req in finished:
-            self.finished[req.rid] = req
-            self.events.append({"event": "request_done", "rid": req.rid,
-                                "reason": req.finish_reason,
-                                "n_tokens": len(req.tokens),
-                                "latency_s": req.latency_s})
+            if n_live:
+                with self.spans("decode", live=n_live,
+                                dead=self.sched.max_batch - n_live):
+                    tok, pos, temp, top_k, seeds, n_gen = \
+                        self.sched.batch_arrays()
+                    nxt = self._decode_batch(
+                        tok, pos, temp, top_k, seeds, n_gen,
+                        bool((self.sched.temp > 0).any()),
+                        bool((self.sched.top_k > 0).any()))
+                with self.spans("decode.wait"):
+                    nxt = np.asarray(nxt)
+                self._decode_steps += 1
+                self._decode_tokens += n_live
+            with self.spans("finish"):
+                if n_live:
+                    finished.extend(self.sched.record_step(nxt))
+                self._post_finish(finished)
+                for req in finished:
+                    self.finished[req.rid] = req
+                    self.events.append({"event": "request_done",
+                                        "rid": req.rid,
+                                        "reason": req.finish_reason,
+                                        "n_tokens": len(req.tokens),
+                                        "latency_s": req.latency_s})
         return finished
 
     def drain(self) -> List[Request]:
@@ -339,6 +368,8 @@ class ServeEngine:
     def stats(self) -> Dict[str, float]:
         lat = [r.latency_s for r in self.finished.values()
                if r.latency_s is not None]
+        decode_time = self.spans.totals["decode"] + \
+            self.spans.totals["decode.wait"]
         return {
             "n_finished": float(len(self.finished)),
             "prefill_tokens": float(self._prefill_tokens),
@@ -347,9 +378,8 @@ class ServeEngine:
                                                         1e-9),
             "decode_steps": float(self._decode_steps),
             "decode_tokens": float(self._decode_tokens),
-            "decode_time_s": self._decode_time,
-            "decode_tok_s": self._decode_tokens / max(self._decode_time,
-                                                      1e-9),
+            "decode_time_s": decode_time,
+            "decode_tok_s": self._decode_tokens / max(decode_time, 1e-9),
             "mean_latency_s": float(np.mean(lat)) if lat else 0.0,
         }
 
@@ -541,6 +571,8 @@ class PagedServeEngine(ServeEngine):
         job = self._jobs[0]
         req, T, ps = job.req, int(job.req.prompt.size), self.page_size
         qc = self.qcfg
+        if not job.n_chunks:
+            req.prefill_t = time.perf_counter()
         if not self.chunk:
             logits, one_cache, _ = self._prefill_one(req)
             one_leaves = jax.tree_util.tree_leaves(one_cache)
@@ -594,7 +626,8 @@ class PagedServeEngine(ServeEngine):
             self._jobs.popleft()
 
     def _admit(self) -> List[Request]:
-        finished = self._start_jobs()
+        with self.spans("admit"):
+            finished = self._start_jobs()
         # Refill an under-occupied batch fast: with idle rows the decode
         # step is paying fixed cost anyway, so run one prefill chunk per
         # idle row (min 1) instead of strictly one per step; a full batch
@@ -603,7 +636,12 @@ class PagedServeEngine(ServeEngine):
         for _ in range(budget):
             if not self._jobs:
                 break
-            self._advance_job()
+            job = self._jobs[0]
+            T = int(job.req.prompt.size)
+            real = min(T - job.next_start, self.chunk_size) \
+                if self.chunk else T
+            with self.spans("prefill", tokens=real):
+                self._advance_job()
         finished.extend(self._place_ready())
         return finished
 
@@ -618,25 +656,20 @@ class PagedServeEngine(ServeEngine):
             job, first = self._ready.pop(0)
             req = job.req
             T = int(req.prompt.size)
-            tok0 = int(first[0])
-            dt = time.perf_counter() - job.t0
-            self._prefill_tokens += T
-            self._prefill_time += dt
-            self.events.append({"event": "prefill", "rid": req.rid,
-                                "slot": job.slot, "prompt_len": T,
-                                "padded_len": T, "fused": self.fused,
-                                "chunks": job.n_chunks,
-                                "shared_pages": job.n_shared,
-                                "time_s": dt})
-            self._reserved.discard(job.slot)
-            self._slot_rid[job.slot] = req.rid
-            self._admit_seq[job.slot] = self._seq
-            self._seq += 1
-            if self.prefix_sharing:
-                full = T // self.page_size
-                self.alloc.register(job.chain[:full], job.pages[:full])
-            if self.sched.place(job.slot, req, tok0, T):
-                finished.append(req)
+            with self.spans("prefill.wait"):
+                tok0 = int(first[0])
+            with self.spans("place"):
+                self._reserved.discard(job.slot)
+                self._slot_rid[job.slot] = req.rid
+                self._admit_seq[job.slot] = self._seq
+                self._seq += 1
+                if self.prefix_sharing:
+                    full = T // self.page_size
+                    self.alloc.register(job.chain[:full], job.pages[:full])
+                if self._place(job.slot, req, tok0, job.t0, T,
+                               chunks=job.n_chunks,
+                               shared_pages=job.n_shared):
+                    finished.append(req)
         return finished
 
     # ---- page lifecycle ----------------------------------------------------
@@ -667,6 +700,7 @@ class PagedServeEngine(ServeEngine):
         self._scrub_slot(victim)
         self._release_slot(victim)
         req.tokens.clear()
+        req.prefill_t = None
         req.first_token_t = None
         self.sched.queue.appendleft(req)
         self._preemptions += 1

@@ -44,6 +44,7 @@ class Request:
     prompt: np.ndarray                       # (T,) int32
     sampling: SamplingParams
     submit_t: float = 0.0
+    prefill_t: Optional[float] = None        # its first chunk dispatched
     first_token_t: Optional[float] = None
     finish_t: Optional[float] = None
     tokens: List[int] = dataclasses.field(default_factory=list)
