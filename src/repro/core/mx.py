@@ -14,9 +14,16 @@ Scale modes:
   * "adaptive" — choose between floor-exp and floor-exp+1 per block by
                  least squared error (the paper's "scale that adapts" future
                  direction, §6.1; a beyond-paper feature we evaluate).
+
+:class:`MXWeight` is the packed ("at rest") form of a quantized weight:
+element codes of one byte each plus one E8M0 shared exponent per block,
+as MX hardware stores it.  :func:`pack_mx` builds it once, for weights that
+never change (serving); :func:`unpack_mx` gives back exactly what
+``quantize_mx(w, fmt, axis=-2)`` gives.
 """
 from __future__ import annotations
 
+import dataclasses
 from functools import partial
 from typing import Optional, Tuple
 
@@ -28,7 +35,8 @@ from .formats import (SCALE_EMAX, SCALE_EMIN, ElementFormat, exp2_int,
 
 __all__ = [
     "quantize_mx", "mx_stats", "block_reshape", "block_unreshape",
-    "shared_exponent", "MX_BLOCK",
+    "shared_exponent", "MX_BLOCK", "MXWeight", "pack_mx", "unpack_mx",
+    "decode_codes",
 ]
 
 MX_BLOCK = 32  # hardware block size (paper trains with k=32 throughout)
@@ -163,3 +171,121 @@ def mx_stats(x: jax.Array, fmt: ElementFormat, axis: int = -1,
         "tight_block_frac": tight_block_frac,
         "rel_err": rel_err,
     }
+
+
+# ---------------------------------------------------------------------------
+# Packed weights: element codes + E8M0 shared exponents
+# ---------------------------------------------------------------------------
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class MXWeight:
+    """An MX-quantized weight ``(..., K, N)`` stored packed, blocks along K.
+
+    ``codes``: uint8 ``(..., K, N)``, one element code each (sign, then
+    ``fmt.ebits`` exponent and ``fmt.mbits`` mantissa bits, right-aligned).
+    ``exps``: uint8 ``(..., K // block, N)``, each block's E8M0 shared
+    exponent (biased by 127).  ``fmt`` is static pytree metadata, so
+    ``lax.scan`` over a stacked leading axis slices both arrays together.
+    """
+
+    codes: jax.Array
+    exps: jax.Array
+    fmt: ElementFormat = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def shape(self) -> Tuple[int, ...]:
+        return self.codes.shape
+
+    @property
+    def ndim(self) -> int:
+        return self.codes.ndim
+
+    @property
+    def block(self) -> int:
+        return self.codes.shape[-2] // self.exps.shape[-2]
+
+    @property
+    def nbytes(self) -> int:
+        return self.codes.nbytes + self.exps.nbytes
+
+
+def _encode_elem(q: jax.Array, fmt: ElementFormat) -> jax.Array:
+    """fp32 values on ``fmt``'s grid -> element codes (int32).  Zeros of
+    either sign encode as +0, the zero ``quantize_mx`` returns."""
+    bits = jax.lax.bitcast_convert_type(q, jnp.uint32)
+    sign = (bits >> 31).astype(jnp.int32)
+    e = ((bits >> 23) & 0xFF).astype(jnp.int32) - 127 + fmt.bias
+    m = ((bits & 0x7FFFFF) >> (23 - fmt.mbits)).astype(jnp.int32)
+    normal = (e << fmt.mbits) | m
+    # Subnormal codes (exponent field 0) count quanta of the subnormal step.
+    sub = jnp.round(jnp.abs(q) / fmt.min_subnormal).astype(jnp.int32)
+    mag = jnp.where(e >= 1, normal, sub)
+    return jnp.where(mag > 0, (sign << (fmt.ebits + fmt.mbits)) | mag, 0)
+
+
+def decode_codes(c: jax.Array, fmt: ElementFormat) -> jax.Array:
+    """Element codes (int32) -> their fp32 values, with integer and float
+    ops alone: normal codes move their exponent and mantissa fields into
+    an fp32 bit pattern; subnormal codes (exponent field 0), which an fp32
+    pattern with the format's bias cannot express without fp32 subnormals,
+    scale their mantissa by the subnormal step instead.  The Pallas GEMM
+    runs this same function on its weight tiles."""
+    width = fmt.ebits + fmt.mbits
+    mag = c & ((1 << width) - 1)
+    sign = (c >> width) << 31
+    normal = jax.lax.bitcast_convert_type(
+        (mag << (23 - fmt.mbits)) + ((127 - fmt.bias) << 23), jnp.float32)
+    sub = mag.astype(jnp.float32) * fmt.min_subnormal
+    val = jnp.where(mag < (1 << fmt.mbits), sub, normal)
+    return jax.lax.bitcast_convert_type(
+        jax.lax.bitcast_convert_type(val, jnp.int32) | sign, jnp.float32)
+
+
+def _pack_2d(w: jax.Array, fmt: ElementFormat, block: int, scale_mode: str,
+             dtype) -> Tuple[jax.Array, jax.Array]:
+    w = w.astype(dtype)
+    xb, _ = block_reshape(w.astype(jnp.float32), 0, block)   # (N, nb, blk)
+    e = shared_exponent(xb, fmt, scale_mode)
+    q = quantize_elem(xb / exp2_int(e), fmt)
+    codes = _encode_elem(q, fmt).astype(jnp.uint8)
+    codes = codes.reshape(codes.shape[0], -1).T               # (K, N)
+    exps = (e[..., 0] + 127).astype(jnp.uint8).T              # (nb, N)
+    return codes, exps
+
+
+@partial(jax.jit, static_argnames=("fmt", "block", "scale_mode", "dtype"))
+def pack_mx(w: jax.Array, fmt: ElementFormat, block: int = MX_BLOCK,
+            scale_mode: str = "floor", dtype=None) -> MXWeight:
+    """Pack ``w (..., K, N)`` into an :class:`MXWeight`, blocks along K.
+
+    What is packed is ``quantize_mx(w.astype(dtype), fmt, axis=-2)``
+    (``dtype`` None: ``w.dtype``), so a consumer that casts its weight
+    before quantizing, as ``qdense`` casts to the activations' dtype, is
+    matched bit for bit.  K must be a multiple of ``block``.  Leading axes
+    (a stack of layers) are packed one (K, N) slice at a time, so the
+    temporaries never exceed one slice.  ``fmt`` must fit a byte."""
+    if fmt.bits > 8:
+        raise ValueError(f"{fmt} does not fit one byte per element")
+    if w.ndim < 2 or w.shape[-2] % block:
+        raise ValueError(f"K of {w.shape} is not a multiple of {block}")
+    lead, (k, n) = w.shape[:-2], w.shape[-2:]
+    one = partial(_pack_2d, fmt=fmt, block=block, scale_mode=scale_mode,
+                  dtype=dtype or w.dtype)
+    if not lead:
+        codes, exps = one(w)
+    else:
+        codes, exps = jax.lax.map(one, w.reshape((-1, k, n)))
+        codes = codes.reshape(lead + (k, n))
+        exps = exps.reshape(lead + (k // block, n))
+    return MXWeight(codes, exps, fmt)
+
+
+@partial(jax.jit, static_argnames=("dtype",))
+def unpack_mx(w: MXWeight, dtype=jnp.bfloat16) -> jax.Array:
+    """The dequantized weight: bit for bit ``quantize_mx(W, fmt, axis=-2)``
+    of the ``W`` that was packed (for the scale mode it was packed with),
+    in ``dtype``."""
+    q = decode_codes(w.codes.astype(jnp.int32), w.fmt)
+    scale = exp2_int(w.exps.astype(jnp.int32) - 127)
+    scale = jnp.repeat(scale, w.block, axis=-2)
+    return (q * scale).astype(dtype)

@@ -7,6 +7,8 @@ with results dequantized to a higher precision format after the operation"
 contraction in the codebase, dispatching on ``kind``:
 
   "dense"        x (..., K) @ W (K, N) — projections / MLP / LM head.
+                 W may be an :class:`~repro.core.mx.MXWeight` (weights
+                 packed at rest for serving): forward only, no VJP.
                  Custom VJP with per-GEMM quantization axes:
                    forward : y  = Q[a_fwd](x) · Q[w_fwd](W)   blocks along K
                    dgrad   : dx = Q[g_bwd](dy) · Q[w_bwd](W)ᵀ blocks along N
@@ -73,7 +75,7 @@ import jax
 import jax.numpy as jnp
 
 from .attnspec import AttnSpec
-from .mx import quantize_mx
+from .mx import MXWeight, quantize_mx, unpack_mx
 from .qconfig import QuantConfig
 
 __all__ = ["mx_contract", "qmatmul", "qeinsum_bmm", "qdot_attn",
@@ -145,6 +147,25 @@ def _dense(x: jax.Array, w: jax.Array, cfg: QuantConfig) -> jax.Array:
 
 
 def _dense_fwd(x, w, cfg: QuantConfig):
+    """Forward of the dense GEMM, by what ``w`` is.
+
+    ``w`` an array (training): the fused kernel reads the bf16 weight and
+    quantizes both operands on load; the emulation quantizes both with
+    ``quantize_mx``.  ``w`` an :class:`MXWeight` (serving, packed once
+    from Q[w_fwd](W)): the fused kernel reads its codes and exponents and
+    dequantizes on load; the emulation multiplies Q[a_fwd](x) by
+    ``unpack_mx(w)``, which is Q[w_fwd](W) bit for bit, and never
+    re-quantizes it (MX re-quantization is not idempotent at block maxima
+    that round up)."""
+    if isinstance(w, MXWeight):
+        if _fused(cfg, cfg.a_fwd, w.fmt):
+            y = _kernels().mx_matmul(x, w, cfg.a_fwd, None,
+                                     block=cfg.block).astype(x.dtype)
+        else:
+            xq = quantize_mx(x, cfg.a_fwd, axis=-1, block=cfg.block,
+                             scale_mode=cfg.scale_mode)
+            y = _mm(xq, unpack_mx(w, x.dtype), x.dtype)
+        return y, (x, w)
     if _fused(cfg, cfg.a_fwd, cfg.w_fwd):
         y = _kernels().mx_matmul(x, w, cfg.a_fwd, cfg.w_fwd,
                                  block=cfg.block).astype(x.dtype)
@@ -243,6 +264,8 @@ def _register(kind: str):
 
 @_register("dense")
 def _kind_dense(lhs, rhs, cfg, *, spec, valid, pages):
+    if isinstance(rhs, MXWeight):       # frozen weights: no VJP to carry
+        return _dense_fwd(lhs, rhs, cfg)[0]
     return _dense(lhs, rhs, cfg)
 
 

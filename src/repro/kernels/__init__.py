@@ -1,7 +1,9 @@
 """Pallas TPU kernels for the MX quantization hot-spots.
 
   mx_quant.py      — fused block-scale quantize-dequantize (VPU, VMEM-tiled)
-  mx_matmul.py     — forward MX GEMM, quantize-on-load, fp32 accum (MXU)
+  mx_matmul.py     — forward MX GEMM, fp32 accum (MXU): quantize-on-load for
+                     bf16 weights (training), dequantize-on-load for
+                     weights packed at rest (MXWeight, serving)
   mx_matmul_bwd.py — backward MX GEMMs: dgrad + wgrad, quantize-on-load
   mx_attention.py  — flash attention: fwd (online softmax, tile-skipping),
                      dgrad pair (dQ + dK/dV), decode (Tq=1) — both BMMs in

@@ -55,7 +55,8 @@ def mx_matmul(a: jax.Array, b: jax.Array,
               fmt_a: Optional[ElementFormat],
               fmt_b: Optional[ElementFormat],
               block: int = MX_BLOCK) -> jax.Array:
-    """Kernel-backed ``a (..., K) @ b (K, N)`` with MX-quantized operands."""
+    """Kernel-backed ``a (..., K) @ b (K, N)`` with MX-quantized operands;
+    ``b`` an ``MXWeight`` takes the dequantize-on-load path."""
     if a.shape[-1] % block:
         return ref.mx_matmul_ref(a, b, fmt_a, fmt_b, block=block)
     lead = a.shape[:-1]

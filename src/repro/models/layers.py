@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import QuantConfig, mx_contract, quantize_mx
+from repro.core.mx import MXWeight
 
 PARAM_DTYPE = jnp.float32     # master copies live in the optimizer
 COMPUTE_DTYPE = jnp.bfloat16
@@ -65,8 +66,12 @@ def qdense(p, x: jax.Array, qcfg: QuantConfig) -> jax.Array:
     The projection runs through the "dense" custom VJP of `mx_contract`,
     so its forward, dgrad, and wgrad GEMMs each hit the fused
     quantize-on-load Pallas kernels in their per-pass formats (a_fwd/w_fwd,
-    g_bwd/w_bwd, a_bwd/g_bwd) whenever ``qcfg`` is kernel-eligible."""
-    w = p["w"].astype(x.dtype)
+    g_bwd/w_bwd, a_bwd/g_bwd) whenever ``qcfg`` is kernel-eligible.  A
+    weight packed at rest (``MXWeight``, see ``pack_serving_weights``) is
+    passed as it is."""
+    w = p["w"]
+    if not isinstance(w, MXWeight):
+        w = w.astype(x.dtype)
     y = mx_contract(x, w, qcfg, kind="dense")
     if "b" in p:
         y = y + p["b"].astype(y.dtype)

@@ -34,6 +34,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import AttnSpec, QuantConfig, mx_contract
+from repro.core.mx import MXWeight, pack_mx
 from repro.parallel.sharding import shard_act
 from .layers import (COMPUTE_DTYPE, apply_norm, dense_init, embed_init,
                      embed_lookup, norm_init, qdense)
@@ -51,7 +52,8 @@ from .xlstm import (mlstm_apply, mlstm_decode, mlstm_init, mlstm_prefill,
 __all__ = ["LMConfig", "lm_init", "lm_apply", "lm_loss", "init_cache",
            "init_cache_paged", "paged_leaf_mask", "kind_paged",
            "lm_decode_step", "lm_prefill", "lm_prefill_chunk",
-           "prefill_supported", "chunk_supported", "block_plan"]
+           "prefill_supported", "chunk_supported", "block_plan",
+           "pack_serving_weights"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -414,10 +416,63 @@ def lm_apply(params, batch, cfg: LMConfig, qcfg: QuantConfig):
 
 
 def _head_matmul(params, h, cfg, qcfg):
-    if cfg.tie_embeddings:
-        w = params["embed"]["table"].astype(h.dtype).T
-        return mx_contract(h, w, qcfg, kind="dense")
-    return qdense(params["lm_head"], h, qcfg)
+    # A tied head packed for serving sits under "lm_head" beside the table.
+    if "lm_head" in params:
+        return qdense(params["lm_head"], h, qcfg)
+    w = params["embed"]["table"].astype(h.dtype).T
+    return mx_contract(h, w, qcfg, kind="dense")
+
+
+def pack_serving_weights(params, cfg: LMConfig, qcfg: QuantConfig):
+    """The weights a server holds: every dense projection that ``qdense``
+    or the tied head consumes, MX-packed at rest (:class:`MXWeight`), so
+    the forward GEMMs dequantize codes on load instead of re-quantizing
+    bf16 weights on every call.
+
+    Packs only where ``qcfg`` quantizes weights (``w_fwd``, one byte an
+    element) and K is a multiple of ``qcfg.block``, from the weight in the
+    compute dtype, as ``qdense`` casts it before quantizing.  Every other
+    leaf is the caller's own array, untouched: embeddings (lookup), norms,
+    biases, MoE experts (the "bmm" kind) and MLA attention, whose absorbed
+    decode reads ``w_uk``/``w_uv`` raw.  A tied head is packed from
+    ``table.T`` into ``lm_head``; the table stays for the lookup.  Returns
+    ``(params, stats)``: ``packed_weight_share`` is the share of dense
+    projection parameters served packed, ``packed_weight_bytes`` their
+    bytes at rest.  The caller's ``params`` are neither mutated nor
+    donated; the result holds no bf16 copy of a packed leaf."""
+    fmt = qcfg.w_fwd
+    if fmt is None or fmt.bits > 8:
+        return params, {"packed_weight_share": 0.0,
+                        "packed_weight_bytes": 0.0}
+    seen = {"total": 0, "packed": 0, "bytes": 0}
+
+    def pack(w, raw=False):
+        seen["total"] += w.size
+        if raw or w.shape[-2] % qcfg.block:
+            return w
+        mw = pack_mx(w, fmt, qcfg.block, qcfg.scale_mode, COMPUTE_DTYPE)
+        seen["packed"] += w.size
+        seen["bytes"] += mw.nbytes
+        return mw
+
+    def walk(node, mla=False):
+        if isinstance(node, (list, tuple)):
+            return type(node)(walk(x, mla) for x in node)
+        if not isinstance(node, dict):
+            return node
+        mla = mla or "w_uk" in node
+        return {k: pack(v, mla) if k == "w" and hasattr(v, "ndim")
+                and not isinstance(v, MXWeight) else walk(v, mla)
+                for k, v in node.items()}
+
+    packed = walk(params)
+    if cfg.tie_embeddings and "lm_head" not in params:
+        head = pack(params["embed"]["table"].T)
+        if isinstance(head, MXWeight):
+            packed["lm_head"] = {"w": head}
+    stats = {"packed_weight_share": seen["packed"] / max(seen["total"], 1),
+             "packed_weight_bytes": float(seen["bytes"])}
+    return packed, stats
 
 
 def lm_loss(params, batch, cfg: LMConfig, qcfg: QuantConfig):
@@ -679,7 +734,22 @@ def lm_decode_step(params, cache, tok, pos, cfg: LMConfig,
             return h, new_lc
 
         if cfg.scan_layers and n_rep > 1:
-            h, new_gc = jax.lax.scan(body, h, (gp, gc))
+            # The stacked cache rides in the carry, each layer's entry
+            # written back in place: as scan outputs the new entries
+            # would fill a second cache-sized buffer before the (donated)
+            # result, about 4 GB at starcoder2-3b's page pool.
+            def layer(carry, lp, body=body):
+                h, gc, r = carry
+                lc = jax.tree.map(
+                    lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, False),
+                    gc)
+                h, nc = body(h, (lp, lc))
+                gc = jax.tree.map(
+                    lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                        a, n.astype(a.dtype), r, 0), gc, nc)
+                return (h, gc, r + 1), None
+
+            (h, new_gc, _), _ = jax.lax.scan(layer, (h, gc, 0), gp)
         else:
             new_gc_list = []
             for r in range(n_rep):

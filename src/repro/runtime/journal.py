@@ -43,7 +43,7 @@ RECORD_KINDS = frozenset({
     # guard controller
     "guard_transition",
     # serving engines
-    "submit", "prefill", "request_done", "preempt",
+    "submit", "prefill", "request_done", "preempt", "pack_weights",
     # sweep executor
     "sweep_pack", "sweep_run",
     # memory accounting

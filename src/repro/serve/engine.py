@@ -33,6 +33,11 @@ prompt pages are shared across requests by content (prefix cache), and
 page pressure is resolved by LRU eviction of unreferenced cached pages or
 LIFO preemption of the newest request.  Decode runs the same per-row
 positions through ``_serve_step_paged`` with the (B, P) page table.
+
+Both engines serve the dense projections MX-packed at rest
+(``models.pack_serving_weights``) wherever the preset quantizes weights;
+``stats()`` reports ``packed_weight_share`` and ``packed_weight_bytes``,
+and the journal's ``pack_weights`` record holds both from construction.
 """
 from __future__ import annotations
 
@@ -49,8 +54,8 @@ from repro.core import QuantConfig
 from repro.runtime import Journal, MemoryLedger, SegmentFn, Spans
 from repro.models import (LMConfig, block_plan, chunk_supported, init_cache,
                           init_cache_paged, lm_decode_step, lm_prefill,
-                          lm_prefill_chunk, paged_leaf_mask,
-                          prefill_supported)
+                          lm_prefill_chunk, pack_serving_weights,
+                          paged_leaf_mask, prefill_supported)
 from .pages import (PageAllocator, gather_prior, prefix_chain,
                     write_chunk_pages, zero_pages)
 from .scheduler import Request, SamplingParams, Scheduler, sample_tokens
@@ -160,7 +165,9 @@ class ServeEngine:
         if prefill == "fused" and not fused_ok:
             raise ValueError(f"config {cfg.name!r} has no fused prefill "
                              "(encoder-decoder / frontend)")
-        self.params = params
+        # Weights are frozen while serving: the dense projections are
+        # MX-packed at rest once, and the GEMMs dequantize them on load.
+        self.params, self._packing = pack_serving_weights(params, cfg, qcfg)
         self.cfg = cfg
         self.qcfg = qcfg
         self.max_len = max_len
@@ -179,7 +186,8 @@ class ServeEngine:
         # accounting at init describes the whole run
         self.events: Journal = Journal()
         self.ledger = MemoryLedger(name="serve")
-        self.ledger.account("params", params)
+        self.events.append({"event": "pack_weights", **self._packing})
+        self.ledger.account("params", self.params)
         self.ledger.account("cache", self.cache)
         # host phases of each step, on the profiler's clock (serve.*)
         self.spans = Spans("serve")
@@ -381,6 +389,7 @@ class ServeEngine:
             "decode_time_s": decode_time,
             "decode_tok_s": self._decode_tokens / max(decode_time, 1e-9),
             "mean_latency_s": float(np.mean(lat)) if lat else 0.0,
+            **self._packing,
         }
 
 

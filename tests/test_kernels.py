@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import (E2M1, E2M3, E3M2, E4M3, E5M2, QuantConfig, preset,
-                        use_fused_gemms)
+                        quantize_mx, use_fused_gemms)
 from repro.kernels import (mx_matmul, mx_matmul_dgrad, mx_matmul_dgrad_ref,
                            mx_matmul_ref, mx_matmul_wgrad,
                            mx_matmul_wgrad_ref, mx_quantize, mx_quantize_ref)
@@ -79,6 +79,111 @@ def test_matmul_zero_padding_blocks_are_inert():
     y_k = mx_matmul(a, b, E4M3, E4M3)   # tiles force padding on M/N
     y_r = mx_matmul_ref(a, b, E4M3, E4M3)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), rtol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# Weights packed at rest (MXWeight): pack/unpack and dequantize-on-load
+# ---------------------------------------------------------------------------
+def _edge_weights(shape, seed=3):
+    """Weights whose 32-blocks along K span 2^-12..2^12 of scale, with an
+    all-zero block, a block of signed zeros, a block whose max clamps to
+    448 (E4M3) and a block whose small values land on subnormal codes."""
+    rng = np.random.RandomState(seed)
+    *lead, k, n = shape
+    w = rng.randn(*shape).astype(np.float32)
+    w *= np.exp2(rng.randint(-12, 13, size=(*lead, k // 32, 1, n))
+                 ).repeat(32, axis=-2).reshape(shape)
+    w[..., :32, 0] = 0.0
+    w[..., 32:64, 1] = -0.0
+    w[..., :32, 2] = 1.0
+    w[..., 0, 2] = 479.0 / 256.0            # 479/256 * 256 rounds past 448
+    # scale 2^-8 (max 1.5), so E4M3's subnormal step 2^-9 is 2^-17 here;
+    # odd multiples of 2^-18 sit half-way between two codes
+    w[..., 32:64, 3] = -np.arange(32) % 16 * np.float32(2.0 ** -18)
+    w[..., 32, 3] = 1.5
+    return w
+
+
+@pytest.mark.parametrize("fmt", FMTS, ids=lambda f: f.name)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", [(96, 160), (3, 64, 256)], ids=str)
+def test_pack_unpack_round_trip_is_quantize_mx(fmt, dtype, shape):
+    """unpack(pack(W)) is quantize_mx(W, axis=-2) bit for bit, zero blocks,
+    subnormal codes, the clamp and signed zeros included; a stacked
+    leading axis packs layer by layer."""
+    from repro.core.mx import pack_mx, unpack_mx
+    w = jnp.asarray(_edge_weights(shape)).astype(dtype)
+    mw = pack_mx(w, fmt)
+    assert mw.codes.dtype == jnp.uint8 and mw.codes.shape == w.shape
+    assert mw.exps.dtype == jnp.uint8
+    assert mw.exps.shape == w.shape[:-2] + (w.shape[-2] // 32, w.shape[-1])
+    got = np.asarray(unpack_mx(mw, dtype))
+    want = np.asarray(quantize_mx(w, fmt, axis=-2))
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+    if fmt is E4M3:
+        codes = np.asarray(mw.codes)
+        assert (codes[..., 0, 2] == 0x7E).all()          # 448: S.1111.110
+        assert ((codes[..., 32:64, 3] & 0x78) == 0).any()  # subnormal codes
+        assert (codes[..., 32:64, 1] == 0).all()         # -0 packs as +0
+
+
+@pytest.mark.parametrize("m", [1, 64, 256])
+@pytest.mark.parametrize("k,n,tiles", [
+    (256, 256, None),                  # wk / wv: N = 256, one tile
+    (384, 1536, None),                 # wq / w_up: N in 512-wide tiles
+    (2048, 384, (64, 128, 1024)),      # w_down: K over two K tiles
+    (96, 160, None),                   # N padded to a lane tile
+    (1056, 160, (64, 128, 256)),       # K and N padded to given tiles
+], ids=str)
+@pytest.mark.parametrize("fa", [None, E4M3], ids=["bf16", "e4m3"])
+def test_packed_matmul_bit_identical_to_quantize_on_load(m, k, n, tiles, fa):
+    """The dequantize-on-load path of mx_matmul_pallas (an MXWeight b)
+    equals its quantize-on-load path (bf16 b) bit for bit, at the same
+    tiles: the packed path's own tiles, or given ones.  The packed path
+    pads M to whole 16-row tiles and N to whole tiles, so the
+    quantize-on-load path gets the same zero rows and columns (it would
+    otherwise shrink its tiles to M and N, and XLA:CPU sums dots of other
+    shapes in other orders; at M = 1 a vector-matrix dot fused with the
+    quantizer)."""
+    from repro.core.mx import pack_mx
+    from repro.kernels.mx_matmul import _packed_tiles, mx_matmul_pallas
+    rng = np.random.RandomState(m + k + n)
+    a = jnp.asarray(rng.randn(m, k).astype(np.float32)).astype(jnp.bfloat16)
+    w = jnp.asarray(_edge_weights((k, n)) / 64).astype(jnp.bfloat16)
+    tm, tn, tk = tiles or _packed_tiles(m, k, n, 32, fa is not None)
+    want = mx_matmul_pallas(jnp.pad(a, ((0, -m % tm), (0, 0))),
+                            jnp.pad(w, ((0, 0), (0, -n % tn))), fa, E4M3,
+                            tile_m=tm, tile_n=tn, tile_k=tk,
+                            interpret=True)[:m, :n]
+    got = mx_matmul_pallas(
+        a, pack_mx(w, E4M3), fa, None, interpret=True,
+        **({} if tiles is None else dict(tile_m=tm, tile_n=tn, tile_k=tk)))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_packed_dense_contract_dispatch():
+    """mx_contract(kind="dense") given an MXWeight: the emulation
+    (Q(x) @ unpack(w)) and the fused path (the kernel, interpret mode)
+    give what the bf16 weight gives on the emulation path, bit for bit."""
+    from repro.core import mx_contract
+    from repro.core.mx import pack_mx
+    rng = np.random.RandomState(5)
+    x = jnp.asarray(rng.randn(2, 8, 256).astype(np.float32)
+                    ).astype(jnp.bfloat16)
+    w = jnp.asarray(_edge_weights((256, 128)) / 16).astype(jnp.bfloat16)
+    for name in ("e4m3_bf16act", "e4m3_fwd_only"):
+        cfg = preset(name)
+        mw = pack_mx(w, cfg.w_fwd)
+        want = mx_contract(x, w, cfg, kind="dense")
+        got = mx_contract(x, mw, cfg, kind="dense")
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(want, np.float32))
+        with use_fused_gemms(True):
+            fused = mx_contract(x, mw, cfg, kind="dense")
+        np.testing.assert_array_equal(np.asarray(fused, np.float32),
+                                      np.asarray(want, np.float32))
 
 
 # ---------------------------------------------------------------------------

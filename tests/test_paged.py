@@ -16,15 +16,19 @@ here pins that claim and the host-side allocator's bookkeeping:
     accounting survives the full lifecycle (``PageAllocator.check()``);
   * requests that can never fit fail fast, lone requests that outgrow the
     pool finish "cache_full" at the exact page-capacity boundary;
-  * the paged decode kernel path is bit-identical to gather+slab.
+  * the paged decode kernel path is bit-identical to gather+slab;
+  * weights MX-packed at rest serve the tokens the bf16 weights serve.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import preset
+from repro.core import preset, use_fused_gemms
+from repro.core.mx import MXWeight
 from repro.core.formats import E4M3
 from repro.kernels import (gather_pages, mx_attention_decode,
                            mx_attention_decode_paged,
@@ -309,3 +313,66 @@ def test_paged_decode_kernel_bit_identical_to_gather_plus_slab(fmt):
                               gather_pages(v_pool, pt, H),
                               jnp.repeat(valid, H, axis=0), fmt)
     np.testing.assert_array_equal(np.asarray(o_k), np.asarray(o_s))
+
+
+# ---------------------------------------------------------------------------
+# weights MX-packed at rest (the engine packs; the GEMMs dequantize)
+# ---------------------------------------------------------------------------
+def _tied_starcoder():
+    cfg = dataclasses.replace(get_config("starcoder2-3b", "smoke"),
+                              tie_embeddings=True)
+    return cfg, lm_init(jax.random.PRNGKey(0), cfg)
+
+
+@pytest.mark.parametrize("fused", [False, True],
+                         ids=["emulation", "kernels"])
+def test_packed_weights_serve_the_bf16_weights_greedy_tokens(fused):
+    """Under e4m3_bf16act the engine serves every dense projection and the
+    tied head packed (share 1.0) and decodes the greedy tokens that the
+    same engine decodes from the caller's bf16 weights (quantized on
+    load), with the fused kernels forced on (interpret mode) or off.  The
+    caller's params come out unchanged, leaf for leaf."""
+    cfg, params = _tied_starcoder()
+    before = jax.tree.map(np.array, params)
+    qcfg = preset("e4m3_bf16act")
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(1, cfg.vocab, size=n) for n in (5, 40, 70, 33)]
+    kw = dict(max_batch=3, max_len=128, n_pages=24, page_size=32)
+    with use_fused_gemms(fused):
+        packed = PagedServeEngine(params, cfg, qcfg, **kw)
+        _submit_all(packed, prompts)
+        got = _results(packed)
+        plain = PagedServeEngine(params, cfg, qcfg, **kw)
+        plain.params = params
+        _submit_all(plain, prompts)
+        want = _results(plain)
+    assert got == want
+    assert packed.stats()["packed_weight_share"] == 1.0
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(np.asarray(a), b),
+                 params, before)
+
+
+@pytest.mark.parametrize("prec,share", [("e4m3_bf16act", 1.0),
+                                        ("e4m3_fwd_only", 1.0),
+                                        ("bf16", 0.0)])
+def test_packed_weight_share_and_what_stays_bf16(prec, share):
+    """``packed_weight_share`` is 1.0 where the preset quantizes weights
+    and 0.0 under bf16; packed leaves are MXWeights of 1 + 1/32 bytes a
+    weight, the journal records both numbers once, and every leaf left
+    unpacked (embedding table, norms, biases) is the caller's own array."""
+    cfg, params = _tied_starcoder()
+    eng = ServeEngine(params, cfg, preset(prec), max_batch=2, max_len=64)
+    st = eng.stats()
+    assert st["packed_weight_share"] == share
+    (rec,) = eng.events.of_kind("pack_weights")
+    assert rec["packed_weight_share"] == share
+    ws = [p["w"] for p in jax.tree.leaves(
+        params, is_leaf=lambda x: isinstance(x, dict) and "w" in x)
+        if isinstance(p, dict)]
+    n_weights = sum(w.size for w in ws) + params["embed"]["table"].size
+    assert st["packed_weight_bytes"] == share * n_weights * (1 + 1 / 32)
+    wq = eng.params["blocks"][0]["b0"]["attn"]["wq"]
+    assert isinstance(wq["w"], MXWeight) == bool(share)
+    assert wq["b"] is params["blocks"][0]["b0"]["attn"]["wq"]["b"]
+    assert eng.params["embed"]["table"] is params["embed"]["table"]
+    assert ("lm_head" in eng.params) == bool(share)

@@ -6,7 +6,8 @@ reshape that splits the lane dim, a block that breaks the (8, 128) tiling
 rule, a tile over the scoped VMEM limit) it would refuse on the chip.
 Interpret-mode tests cannot see any of that, so these compiles guard the
 kernels at olmo-paper widths (d_model 512, d_ff 2048, vocab 32000,
-d_head 64, context 512).  Nothing runs: a compile that passes is not a
+d_head 64, context 512), and the packed serving GEMM at starcoder2-3b's.
+Nothing runs: a compile that passes is not a
 chip run.
 
 The topology is described inside a module fixture, never at import: only
@@ -69,6 +70,23 @@ def _compile(one_chip, fn, *shapes):
 def test_mx_matmul_fwd_compiles(one_chip, m, k, n):
     _compile(one_chip, lambda a, b: mx_matmul_pallas(a, b, E4M3, E4M3),
              ((m, k), BF16), ((k, n), BF16))
+
+
+@pytest.mark.parametrize("m", [64, 256])
+@pytest.mark.parametrize("k,n", [(3072, 12288), (12288, 3072), (3072, 256)],
+                         ids=str)
+def test_mx_matmul_packed_compiles(one_chip, m, k, n):
+    """The dequantize-on-load path at starcoder2-3b's serving shapes: a
+    64-row decode step and a 256-row prefill chunk against w_up, w_down
+    and wk/wv packed (E4M3 codes + E8M0 exponents, one byte each).  It
+    keeps the kernel's label, which the benchmark's roofline reads."""
+    from repro.core.mx import MXWeight
+    text = _compile(
+        one_chip,
+        lambda a, c, e: mx_matmul_pallas(a, MXWeight(c, e, E4M3), None,
+                                         None),
+        ((m, k), BF16), ((k, n), jnp.uint8), ((k // 32, n), jnp.uint8))
+    assert "%mx_matmul_pallas" in text
 
 
 @pytest.mark.parametrize("m,k,n", [(TOKENS, D, FF), (TOKENS, D, VOCAB)],
@@ -135,3 +153,39 @@ def test_paged_decode_compiles(one_chip, fmt, view, dh):
              ((B * H, 1, dh), BF16), ((N, H, ps, dh), BF16),
              ((N, H, ps, dh), BF16), ((B, P), jnp.int32),
              ((B, P * ps), jnp.bool_))
+
+
+def test_paged_serve_step_packed_updates_pool_in_place(one_chip,
+                                                       monkeypatch):
+    """The paged decode step at starcoder2-3b widths, weights packed, 64
+    rows over a 4096-page pool of 32: it compiles for the chip with the
+    packed GEMM in it, and its temporaries stay far below one pool.  The
+    stacked cache is updated in place; as scan outputs the new entries
+    took a second pool-sized buffer (3.9 GB), which beside packed weights
+    and a caller's bf16 copy no longer fit the chip's 16 GB."""
+    import repro.kernels.ops as ops
+    from repro.core import preset, use_fused_gemms
+    from repro.models import (init_cache_paged, lm_decode_step, lm_init,
+                              pack_serving_weights)
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    cfg, qcfg = get_config("starcoder2-3b", "full"), preset("e4m3_bf16act")
+    params = jax.eval_shape(lambda: pack_serving_weights(
+        lm_init(jax.random.PRNGKey(0), cfg), cfg, qcfg)[0])
+    cache = jax.eval_shape(lambda: init_cache_paged(cfg, 64, 2048, 4096, 32))
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        t)
+
+    def step(p, c, tok, pos, pt):
+        return lm_decode_step(p, c, tok, pos, cfg, qcfg, page_table=pt,
+                              page_size=32)
+
+    with use_fused_gemms(True):
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            on_chip(params), on_chip(cache),
+            *on_chip((jax.ShapeDtypeStruct((64, 1), jnp.int32),
+                      jax.ShapeDtypeStruct((64,), jnp.int32),
+                      jax.ShapeDtypeStruct((64, 64), jnp.int32)))).compile()
+    assert "%mx_matmul_pallas" in compiled.as_text()
+    pool = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool / 8
