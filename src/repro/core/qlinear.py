@@ -14,7 +14,8 @@ contraction in the codebase, dispatching on ``kind``:
                    dgrad   : dx = Q[g_bwd](dy) · Q[w_bwd](W)ᵀ blocks along N
                    wgrad   : dW = Q[a_bwd](x)ᵀ · Q[g_bwd](dy) blocks along T
   "bmm"          batched per-expert (..., E, M, K) @ (E, K, N) — vmapped
-                 "dense" so each expert gets its own block scales.
+                 "dense" so each expert gets its own block scales; its
+                 forward kernel is labelled ``mx_expert_matmul_pallas``.
   "attn_qk",
   "attn_pv"      single attention BMM ``a (..., M, K) @ b (..., K, N)``;
                  both operands quantized with a_fwd along the contraction
@@ -140,13 +141,14 @@ def _mm(a: jax.Array, b: jax.Array, out_dtype) -> jax.Array:
 # ---------------------------------------------------------------------------
 # "dense": the projection GEMM custom VJP
 # ---------------------------------------------------------------------------
-@partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _dense(x: jax.Array, w: jax.Array, cfg: QuantConfig) -> jax.Array:
-    y, _ = _dense_fwd(x, w, cfg)
+@partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _dense(x: jax.Array, w: jax.Array, cfg: QuantConfig,
+           expert: bool) -> jax.Array:
+    y, _ = _dense_fwd(x, w, cfg, expert)
     return y
 
 
-def _dense_fwd(x, w, cfg: QuantConfig):
+def _dense_fwd(x, w, cfg: QuantConfig, expert: bool):
     """Forward of the dense GEMM, by what ``w`` is.
 
     ``w`` an array (training): the fused kernel reads the bf16 weight and
@@ -156,19 +158,20 @@ def _dense_fwd(x, w, cfg: QuantConfig):
     dequantizes on load; the emulation multiplies Q[a_fwd](x) by
     ``unpack_mx(w)``, which is Q[w_fwd](W) bit for bit, and never
     re-quantizes it (MX re-quantization is not idempotent at block maxima
-    that round up)."""
+    that round up).  ``expert``: the GEMM of an MoE expert, whose fused
+    kernel is labelled apart."""
     if isinstance(w, MXWeight):
         if _fused(cfg, cfg.a_fwd, w.fmt):
-            y = _kernels().mx_matmul(x, w, cfg.a_fwd, None,
-                                     block=cfg.block).astype(x.dtype)
+            y = _kernels().mx_matmul(x, w, cfg.a_fwd, None, block=cfg.block,
+                                     expert=expert).astype(x.dtype)
         else:
             xq = quantize_mx(x, cfg.a_fwd, axis=-1, block=cfg.block,
                              scale_mode=cfg.scale_mode)
             y = _mm(xq, unpack_mx(w, x.dtype), x.dtype)
         return y, (x, w)
     if _fused(cfg, cfg.a_fwd, cfg.w_fwd):
-        y = _kernels().mx_matmul(x, w, cfg.a_fwd, cfg.w_fwd,
-                                 block=cfg.block).astype(x.dtype)
+        y = _kernels().mx_matmul(x, w, cfg.a_fwd, cfg.w_fwd, block=cfg.block,
+                                 expert=expert).astype(x.dtype)
     else:
         xq = quantize_mx(x, cfg.a_fwd, axis=-1, block=cfg.block,
                          scale_mode=cfg.scale_mode)
@@ -178,7 +181,7 @@ def _dense_fwd(x, w, cfg: QuantConfig):
     return y, (x, w)
 
 
-def _dense_bwd(cfg: QuantConfig, res, dy):
+def _dense_bwd(cfg: QuantConfig, expert: bool, res, dy):
     x, w = res
     kdim, ndim = w.shape
     dyf = dy.reshape(-1, ndim)
@@ -265,8 +268,8 @@ def _register(kind: str):
 @_register("dense")
 def _kind_dense(lhs, rhs, cfg, *, spec, valid, pages):
     if isinstance(rhs, MXWeight):       # frozen weights: no VJP to carry
-        return _dense_fwd(lhs, rhs, cfg)[0]
-    return _dense(lhs, rhs, cfg)
+        return _dense_fwd(lhs, rhs, cfg, False)[0]
+    return _dense(lhs, rhs, cfg, False)
 
 
 @_register("bmm")
@@ -274,9 +277,8 @@ def _kind_bmm(lhs, rhs, cfg, *, spec, valid, pages):
     assert rhs.ndim == 3 and lhs.ndim >= 3
     lead = lhs.shape[:-3]
     xf = lhs.reshape((-1,) + lhs.shape[-3:]) if lead else lhs[None]
-    out = jax.vmap(
-        jax.vmap(_dense, in_axes=(0, 0, None)), in_axes=(0, None, None)
-    )(xf, rhs, cfg)
+    expert = partial(_dense, cfg=cfg, expert=True)
+    out = jax.vmap(jax.vmap(expert), in_axes=(0, None))(xf, rhs)
     return out.reshape(lead + out.shape[1:]) if lead else out[0]
 
 

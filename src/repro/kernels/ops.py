@@ -20,7 +20,7 @@ from . import ref
 from .mx_attention import (attn_tiles, mx_attn_bwd_pallas,
                            mx_attn_decode_paged_pallas,
                            mx_attn_decode_pallas, mx_attn_fwd_pallas)
-from .mx_matmul import mx_matmul_pallas
+from .mx_matmul import mx_expert_matmul_pallas, mx_matmul_pallas
 from .mx_matmul_bwd import mx_matmul_dgrad_pallas, mx_matmul_wgrad_pallas
 from .mx_quant import mx_quantize_pallas
 
@@ -50,19 +50,23 @@ def mx_quantize(x: jax.Array, fmt: Optional[ElementFormat], axis: int = -1,
     return jnp.moveaxis(y2.reshape(lead + (xm.shape[-1],)), -1, ax)
 
 
-@functools.partial(jax.jit, static_argnames=("fmt_a", "fmt_b", "block"))
+@functools.partial(jax.jit, static_argnames=("fmt_a", "fmt_b", "block",
+                                             "expert"))
 def mx_matmul(a: jax.Array, b: jax.Array,
               fmt_a: Optional[ElementFormat],
               fmt_b: Optional[ElementFormat],
-              block: int = MX_BLOCK) -> jax.Array:
+              block: int = MX_BLOCK, expert: bool = False) -> jax.Array:
     """Kernel-backed ``a (..., K) @ b (K, N)`` with MX-quantized operands;
-    ``b`` an ``MXWeight`` takes the dequantize-on-load path."""
+    ``b`` an ``MXWeight`` takes the dequantize-on-load path.  ``expert``
+    runs the kernel as ``mx_expert_matmul_pallas`` (an MoE expert's GEMM,
+    labelled apart in a device trace)."""
     if a.shape[-1] % block:
         return ref.mx_matmul_ref(a, b, fmt_a, fmt_b, block=block)
     lead = a.shape[:-1]
     a2 = a.reshape(-1, a.shape[-1])
-    y2 = mx_matmul_pallas(a2, b, fmt_a, fmt_b, block=block,
-                          interpret=_use_interpret())
+    kernel = mx_expert_matmul_pallas if expert else mx_matmul_pallas
+    y2 = kernel(a2, b, fmt_a, fmt_b, block=block,
+                interpret=_use_interpret())
     return y2.reshape(lead + (b.shape[-1],))
 
 

@@ -1,10 +1,14 @@
-"""Multi-head Latent Attention (DeepSeek-V2) with MX-quantized projections.
+"""Multi-head Latent Attention (DeepSeek-V2/V3) with MX-quantized projections.
 
-Training uses the expanded form (per-head K/V decompressed, chunked flash
-attention); decoding uses the absorbed form operating directly on the
-compressed latent cache (kv_lora + rope dims per position) — the whole
-point of MLA.  All up/down projections are MX GEMMs; the latent cache is
-stored bf16 (the paper quantizes GEMM operands, not state).
+Training and prefill use the expanded form (per-head K/V decompressed,
+chunked flash attention); decoding uses the absorbed form operating
+directly on the compressed latent cache (kv_lora + rope dims per
+position) — the whole point of MLA.  All up/down projections are MX
+GEMMs; the latent cache is stored bf16 (the paper quantizes GEMM
+operands, not state).  Queries come through a low-rank ``w_dq`` /
+``w_uq`` pair with a norm between (``q_lora > 0``, DeepSeek-V2) or one
+``w_q`` projection (``q_lora`` 0, as Moonlight and DeepSeek-V2-Lite
+publish it).
 """
 from __future__ import annotations
 
@@ -20,16 +24,24 @@ from .attention import (flash_attention, paged_valid_mask, _maybe_quant,
                         NEG_INF)
 
 __all__ = ["mla_init", "mla_apply", "mla_decode", "mla_decode_paged",
-           "mla_prefill"]
+           "mla_prefill", "mla_prefill_chunk"]
+
+# The latent norms' epsilon: the published DeepSeek code builds them with
+# its RMSNorm's default, apart from the blocks' ``rms_norm_eps``.
+LATENT_EPS = 1e-6
 
 
 def mla_init(key, d_model: int, n_heads: int, q_lora: int, kv_lora: int,
              nope: int, rope_dim: int, v_head: int, n_layers: int = 1):
     ks = jax.random.split(key, 8)
+    if q_lora:
+        q = {"w_dq": dense_init(ks[0], d_model, q_lora),
+             "q_ln": norm_init(q_lora),
+             "w_uq": dense_init(ks[1], q_lora, n_heads * (nope + rope_dim))}
+    else:
+        q = {"w_q": dense_init(ks[0], d_model, n_heads * (nope + rope_dim))}
     return {
-        "w_dq": dense_init(ks[0], d_model, q_lora),
-        "q_ln": norm_init(q_lora),
-        "w_uq": dense_init(ks[1], q_lora, n_heads * (nope + rope_dim)),
+        **q,
         "w_dkv": dense_init(ks[2], d_model, kv_lora),
         "kv_ln": norm_init(kv_lora),
         "w_uk": dense_init(ks[3], kv_lora, n_heads * nope),
@@ -41,32 +53,49 @@ def mla_init(key, d_model: int, n_heads: int, q_lora: int, kv_lora: int,
 
 
 def _latents(p, x, qcfg, positions, rope_theta):
-    """Compressed queries and the (ckv, k_rope) latent pair."""
+    """Queries (B, T, H*(nope+rope), before rope) and the (ckv, k_rope)
+    latent pair."""
     B, T, _ = x.shape
-    cq = apply_norm(p["q_ln"], qdense(p["w_dq"], x, qcfg), qcfg)
-    ckv = apply_norm(p["kv_ln"], qdense(p["w_dkv"], x, qcfg), qcfg)
+    if "w_q" in p:
+        q = qdense(p["w_q"], x, qcfg)
+    else:
+        q = qdense(p["w_uq"], apply_norm(p["q_ln"], qdense(p["w_dq"], x, qcfg),
+                                         qcfg, eps=LATENT_EPS), qcfg)
+    ckv = apply_norm(p["kv_ln"], qdense(p["w_dkv"], x, qcfg), qcfg,
+                     eps=LATENT_EPS)
     kr = qdense(p["w_kr"], x, qcfg).reshape(B, T, 1, -1)
     kr = rope(kr, positions, rope_theta).reshape(B, T, -1)
-    return cq, ckv, kr
+    return q, ckv, kr
+
+
+def _expanded_attend(p, q, ckv, kr, qcfg, n_heads, nope, rope_dim, v_head,
+                     positions, rope_theta, spec):
+    """Expanded-form attention of queries ``q`` (B, T, H*(nope+rope)) at
+    ``positions`` over the latents ``ckv``/``kr`` (B, S, ·) of positions
+    0..S-1, decompressed per head; ``spec`` places the queries."""
+    B, T, _ = q.shape
+    S = ckv.shape[1]
+    q = q.reshape(B, T, n_heads, nope + rope_dim)
+    q_nope, q_rope = q[..., :nope], q[..., nope:]
+    q_rope = rope(q_rope, positions, rope_theta)
+    k_nope = qdense(p["w_uk"], ckv, qcfg).reshape(B, S, n_heads, nope)
+    v = qdense(p["w_uv"], ckv, qcfg).reshape(B, S, n_heads, v_head)
+    k_rope = jnp.broadcast_to(kr[:, :, None, :], (B, S, n_heads, rope_dim))
+    # Layout for flash: every head is its own "kv head" (group G=1).
+    qf = jnp.concatenate([q_nope, q_rope], -1)[:, :, :, None, :]  # (B,T,H,1,dqk)
+    kf = jnp.concatenate([k_nope, k_rope], -1)      # (B, S, H, dqk)
+    o = flash_attention(qf, kf, v, qcfg, spec)
+    o = o.reshape(B, T, n_heads * v_head)
+    return qdense(p["wo"], o, qcfg)
 
 
 def _forward(p, x, qcfg, n_heads, nope, rope_dim, v_head, positions,
              rope_theta, spec):
     """Full-sequence expanded-form attention; also returns the latents."""
-    B, T, _ = x.shape
-    cq, ckv, kr = _latents(p, x, qcfg, positions, rope_theta)
-    q = qdense(p["w_uq"], cq, qcfg).reshape(B, T, n_heads, nope + rope_dim)
-    q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions, rope_theta)
-    k_nope = qdense(p["w_uk"], ckv, qcfg).reshape(B, T, n_heads, nope)
-    v = qdense(p["w_uv"], ckv, qcfg).reshape(B, T, n_heads, v_head)
-    k_rope = jnp.broadcast_to(kr[:, :, None, :], (B, T, n_heads, rope_dim))
-    # Layout for flash: every head is its own "kv head" (group G=1).
-    qf = jnp.concatenate([q_nope, q_rope], -1)[:, :, :, None, :]  # (B,T,H,1,dqk)
-    kf = jnp.concatenate([k_nope, k_rope], -1)      # (B, T, H, dqk)
-    o = flash_attention(qf, kf, v, qcfg, spec)
-    o = o.reshape(B, T, n_heads * v_head)
-    return qdense(p["wo"], o, qcfg), ckv, kr
+    q, ckv, kr = _latents(p, x, qcfg, positions, rope_theta)
+    out = _expanded_attend(p, q, ckv, kr, qcfg, n_heads, nope, rope_dim,
+                           v_head, positions, rope_theta, spec)
+    return out, ckv, kr
 
 
 def mla_apply(p, x, *, qcfg: QuantConfig, n_heads: int, nope: int,
@@ -93,6 +122,31 @@ def mla_prefill(p, x, *, qcfg: QuantConfig, n_heads: int, nope: int,
     return out, {"ckv": jnp.pad(ckv, pad), "kr": jnp.pad(kr, pad)}
 
 
+def mla_prefill_chunk(p, x, prior_ckv, prior_kr, *, qcfg: QuantConfig,
+                      n_heads: int, nope: int, rope_dim: int, v_head: int,
+                      positions, spec: AttnSpec, kv_mask=None,
+                      rope_theta: float = 1e4):
+    """One chunk of a continuous (chunked) prefill on latent pages.
+
+    x: (B, C, D) at absolute positions ``spec.q_offset ..``;
+    prior_ckv/prior_kr: (B, q_offset, ·) — the prefix latents gathered
+    from the page pools.  The chunk's queries attend, in the expanded
+    form, the prefix and chunk latents decompressed together (what
+    ``mla_prefill`` computes for these rows); returns (out (B, C, D),
+    ckv_chunk, kr_chunk) for the caller to write into fresh pages.
+    ``kv_mask`` ((B, C) bool) zeroes the latents of padded tail
+    positions."""
+    q, ckv, kr = _latents(p, x, qcfg, positions, rope_theta)
+    if kv_mask is not None:
+        ckv = jnp.where(kv_mask[..., None], ckv, 0.0).astype(ckv.dtype)
+        kr = jnp.where(kv_mask[..., None], kr, 0.0).astype(kr.dtype)
+    ckv_all = jnp.concatenate([prior_ckv.astype(ckv.dtype), ckv], axis=1)
+    kr_all = jnp.concatenate([prior_kr.astype(kr.dtype), kr], axis=1)
+    out = _expanded_attend(p, q, ckv_all, kr_all, qcfg, n_heads, nope,
+                           rope_dim, v_head, positions, rope_theta, spec)
+    return out, ckv, kr
+
+
 def mla_decode(p, x, cache, *, qcfg: QuantConfig, n_heads: int, nope: int,
                rope_dim: int, v_head: int, pos, rope_theta: float = 1e4
                ) -> Tuple[jax.Array, dict]:
@@ -108,33 +162,44 @@ def mla_decode(p, x, cache, *, qcfg: QuantConfig, n_heads: int, nope: int,
     kv_lora = cache["ckv"].shape[-1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
-    cq, ckv_new, kr_new = _latents(p, x, qcfg, positions, rope_theta)
+    q, ckv_new, kr_new = _latents(p, x, qcfg, positions, rope_theta)
     rows = jnp.arange(B)
     ckv = cache["ckv"].at[rows, pos].set(
         ckv_new[:, 0].astype(cache["ckv"].dtype))
     kr = cache["kr"].at[rows, pos].set(kr_new[:, 0].astype(cache["kr"].dtype))
 
-    out = _absorbed_attend(p, x, cq, ckv, kr, qcfg, n_heads, nope, rope_dim,
-                           v_head, pos, positions, rope_theta,
+    out = _absorbed_attend(p, x, q, ckv, kr, qcfg, n_heads, nope, rope_dim,
+                           v_head, positions, rope_theta,
                            jnp.arange(S)[None, :] <= pos[:, None])
     return out, {"ckv": ckv, "kr": kr}
 
 
-def _absorbed_attend(p, x, cq, ckv, kr, qcfg, n_heads, nope, rope_dim,
-                     v_head, pos, positions, rope_theta, valid):
+def _absorbed_weight(p, qcfg: QuantConfig, dtype):
+    """A (kv_lora, ·) up-projection as its decompressing GEMM ``qdense``
+    sees it: in ``dtype``, MX-rounded in blocks along kv_lora where the
+    preset quantizes weights, so the absorbed decode and the expanded
+    prefill use the same W_uk / W_uv."""
+    w = p["w"].astype(dtype)
+    return quantize_mx(w, qcfg.w_fwd, axis=0, block=qcfg.block,
+                       scale_mode=qcfg.scale_mode)
+
+
+def _absorbed_attend(p, x, q, ckv, kr, qcfg, n_heads, nope, rope_dim,
+                     v_head, positions, rope_theta, valid):
     """Absorbed-form scoring + context over a contiguous (B, S, ·) latent
     view with a precomputed (B, S) validity mask — shared verbatim by the
     slab and paged decode paths so gathering pages cannot drift from the
     slab numerics."""
     B = x.shape[0]
     kv_lora = ckv.shape[-1]
-    q = qdense(p["w_uq"], cq, qcfg).reshape(B, n_heads, nope + rope_dim)
+    q = q.reshape(B, n_heads, nope + rope_dim)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     q_rope = rope(q_rope[:, None], positions, rope_theta)[:, 0]
-    w_uk = p["w_uk"]["w"].astype(x.dtype).reshape(kv_lora, n_heads, nope)
+    w_uk = _absorbed_weight(p["w_uk"], qcfg, x.dtype).reshape(
+        kv_lora, n_heads, nope)
     # Absorb W_uk into the query: q_eff (B, H, kv_lora).
     q_eff = jnp.einsum("bhd,chd->bhc", _maybe_quant(q_nope, qcfg, -1),
-                       w_uk)
+                       w_uk, preferred_element_type=jnp.float32)
     scale = 1.0 / math.sqrt(nope + rope_dim)
     s = (jnp.einsum("bhc,bsc->bhs", q_eff.astype(jnp.float32),
                     ckv.astype(jnp.float32))
@@ -146,7 +211,8 @@ def _absorbed_attend(p, x, cq, ckv, kr, qcfg, n_heads, nope, rope_dim,
     # route it through the shared dispatcher (pr quantized along the cache
     # axis per row, ckv along the cache axis per column when qcfg.attn).
     ctx = mx_contract(pr, ckv.astype(jnp.float32), qcfg, kind="attn_pv")
-    w_uv = p["w_uv"]["w"].astype(x.dtype).reshape(kv_lora, n_heads, v_head)
+    w_uv = _absorbed_weight(p["w_uv"], qcfg, x.dtype).reshape(
+        kv_lora, n_heads, v_head)
     o = jnp.einsum("bhc,chv->bhv", ctx.astype(x.dtype), w_uv)
     o = o.reshape(B, 1, n_heads * v_head)
     return qdense(p["wo"], o, qcfg)
@@ -169,7 +235,7 @@ def mla_decode_paged(p, x, cache, *, qcfg: QuantConfig, n_heads: int,
     P = page_table.shape[1]
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
     positions = pos[:, None]
-    cq, ckv_new, kr_new = _latents(p, x, qcfg, positions, rope_theta)
+    q, ckv_new, kr_new = _latents(p, x, qcfg, positions, rope_theta)
     rows = jnp.arange(B)
     phys = page_table[rows, pos // ps]
     phys = jnp.where(phys < 0, N, phys)      # negatives wrap: drop instead
@@ -182,6 +248,6 @@ def mla_decode_paged(p, x, cache, *, qcfg: QuantConfig, n_heads: int,
     ckv = ckv_pool[ptc].reshape(B, P * ps, -1)
     kr = kr_pool[ptc].reshape(B, P * ps, -1)
     valid = paged_valid_mask(page_table, pos, ps)
-    out = _absorbed_attend(p, x, cq, ckv, kr, qcfg, n_heads, nope, rope_dim,
-                           v_head, pos, positions, rope_theta, valid)
+    out = _absorbed_attend(p, x, q, ckv, kr, qcfg, n_heads, nope, rope_dim,
+                           v_head, positions, rope_theta, valid)
     return out, {"ckv": ckv_pool, "kr": kr_pool}

@@ -41,7 +41,7 @@ from .layers import (COMPUTE_DTYPE, apply_norm, dense_init, embed_init,
 from .attention import (attention, attention_decode, attention_decode_paged,
                         attention_prefill, attention_prefill_chunk, attn_init)
 from .mla import (mla_apply, mla_decode, mla_decode_paged, mla_init,
-                  mla_prefill)
+                  mla_prefill, mla_prefill_chunk)
 from .mlp import mlp_apply, mlp_init
 from .moe import moe_apply, moe_init
 from .rglru import (rec_block_apply, rec_block_decode, rec_block_init,
@@ -51,7 +51,8 @@ from .xlstm import (mlstm_apply, mlstm_decode, mlstm_init, mlstm_prefill,
 
 __all__ = ["LMConfig", "lm_init", "lm_apply", "lm_loss", "init_cache",
            "init_cache_paged", "paged_leaf_mask", "kind_paged",
-           "lm_decode_step", "lm_prefill", "lm_prefill_chunk",
+           "lm_decode_step", "lm_decode_step_routed", "lm_prefill",
+           "lm_prefill_chunk",
            "prefill_supported", "chunk_supported", "block_plan",
            "pack_serving_weights"]
 
@@ -77,11 +78,17 @@ class LMConfig:
     top_k: int = 0
     n_shared: int = 0                # shared experts (DeepSeek/Moonlight)
     moe_dff: int = 0                 # per-expert hidden dim
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # training; serving routes dropless
     first_dense: int = 0             # leading dense layers before MoE ones
-    # --- MLA (DeepSeek-V2) ---
+    router: str = "softmax"          # "softmax" | "sigmoid" (DeepSeek-V3)
+    routed_scale: float = 1.0        # routed_scaling_factor on the gates
+    # expert parallelism: this layer holds experts [first_expert,
+    # first_expert + experts_held) of the router's n_experts (0 = all)
+    experts_held: int = 0
+    first_expert: int = 0
+    # --- MLA (DeepSeek-V2/V3) ---
     mla: bool = False
-    q_lora: int = 1536
+    q_lora: int = 1536               # 0: queries by one w_q projection
     kv_lora: int = 512
     nope_dim: int = 128
     rope_dim: int = 64
@@ -134,8 +141,9 @@ class LMConfig:
         D, F = self.d_model, self.d_ff
         per_layer = {}
         if self.mla:
-            attn = (D * self.q_lora + self.q_lora * self.n_heads * self.qk_dim
-                    + D * self.kv_lora + self.kv_lora * self.n_heads
+            q = (D * self.q_lora + self.q_lora * self.n_heads * self.qk_dim
+                 if self.q_lora else D * self.n_heads * self.qk_dim)
+            attn = (q + D * self.kv_lora + self.kv_lora * self.n_heads
                     * (self.nope_dim + self.v_head) + D * self.rope_dim
                     + self.n_heads * self.v_head * D)
         else:
@@ -145,7 +153,8 @@ class LMConfig:
         n_mats = 3 if self.act in ("swiglu", "geglu") else 2
         mlp = n_mats * D * F
         if self.n_experts:
-            e = self.top_k if active_only else self.n_experts
+            e = self.top_k if active_only else (self.experts_held
+                                                or self.n_experts)
             moe = n_mats * D * self.moe_dff * (e + self.n_shared) \
                 + D * self.n_experts
         else:
@@ -209,7 +218,8 @@ def _block_init(key, kind: str, cfg: LMConfig):
                                   cfg.qkv_bias, L)
         if cfg.n_experts and kind == "attn":
             p["moe"] = moe_init(ks[1], cfg.d_model, cfg.moe_dff,
-                                cfg.n_experts, cfg.act, L)
+                                cfg.n_experts, cfg.act, L,
+                                held=cfg.experts_held, router=cfg.router)
             if cfg.n_shared:
                 p["shared"] = mlp_init(ks[2], cfg.d_model,
                                        cfg.n_shared * cfg.moe_dff, cfg.act, L)
@@ -241,6 +251,59 @@ def _block_init(key, kind: str, cfg: LMConfig):
     raise ValueError(f"unknown block kind {kind!r}")
 
 
+def _ffn(p, hn, cfg: LMConfig, qcfg: QuantConfig, *, serving: bool,
+         token_mask=None):
+    """The block's feed-forward on normed ``hn`` (B, T, D): the MLP, or the
+    held experts' part of the routed sum plus the shared experts.  Returns
+    (y, moe metrics or None).  Serving routes dropless; ``token_mask``
+    ((B, T) bool) keeps padding and dead rows out of the routing."""
+    if "moe" not in p:
+        return mlp_apply(p["mlp"], hn, qcfg, cfg.act), None
+    B, T, D = hn.shape
+    y, metrics = moe_apply(
+        p["moe"], hn.reshape(B * T, D), qcfg, top_k=cfg.top_k, act=cfg.act,
+        capacity_factor=cfg.capacity_factor, router=cfg.router,
+        routed_scale=cfg.routed_scale, first_expert=cfg.first_expert,
+        dropless=serving,
+        token_mask=None if token_mask is None else token_mask.reshape(-1))
+    y = y.reshape(B, T, D)
+    if "shared" in p:
+        y = y + mlp_apply(p["shared"], hn, qcfg, cfg.act)
+    return y, metrics
+
+
+def _routing(metrics):
+    """A block's routing counters, or None for a block without experts."""
+    if metrics is None:
+        return None
+    return {"held_tokens": metrics["held_tokens"],
+            "dropped": metrics["dropped"]}
+
+
+def _stack_routing(per_block, stacked: bool):
+    """Routing counters of one scan group, ``per_block`` the pattern's
+    blocks' counters (each None or stacked over the group's repetitions
+    when ``stacked``), in layer order: {"held_tokens": (moe_layers,
+    held), "dropped": ()} or None."""
+    rs = [r for r in per_block if r is not None]
+    if not rs:
+        return None
+    held = jnp.stack([r["held_tokens"] for r in rs],
+                     axis=1 if stacked else 0)
+    return {"held_tokens": held.reshape(-1, held.shape[-1]),
+            "dropped": sum(jnp.sum(r["dropped"]) for r in rs)}
+
+
+def _join_routing(groups):
+    """Concatenate the groups' routing counters (None where none has
+    experts)."""
+    rs = [r for r in groups if r is not None]
+    if not rs:
+        return None
+    return {"held_tokens": jnp.concatenate([r["held_tokens"] for r in rs]),
+            "dropped": sum(r["dropped"] for r in rs).astype(jnp.int32)}
+
+
 def _block_apply(h, p, kind: str, cfg: LMConfig, qcfg: QuantConfig,
                  positions, enc_out=None):
     aux = jnp.zeros((), jnp.float32)
@@ -269,17 +332,9 @@ def _block_apply(h, p, kind: str, cfg: LMConfig, qcfg: QuantConfig,
                                   causal=False, q_chunk=cfg.q_chunk,
                                   kv_chunk=cfg.kv_chunk))
         hn2 = apply_norm(p["ln2"], h, qcfg, cfg.norm)
-        if "moe" in p:
-            B, T, D = hn2.shape
-            y, metrics = moe_apply(p["moe"], hn2.reshape(B * T, D), qcfg,
-                                   top_k=cfg.top_k, act=cfg.act,
-                                   capacity_factor=cfg.capacity_factor)
-            y = y.reshape(B, T, D)
-            if "shared" in p:
-                y = y + mlp_apply(p["shared"], hn2, qcfg, cfg.act)
+        y, metrics = _ffn(p, hn2, cfg, qcfg, serving=False)
+        if metrics is not None:
             aux = aux + metrics["aux_loss"]
-        else:
-            y = mlp_apply(p["mlp"], hn2, qcfg, cfg.act)
         return h + y, aux
     if kind == "rec":
         h = h + rec_block_apply(p["rec"], apply_norm(p["ln1"], h, qcfg,
@@ -630,7 +685,9 @@ def paged_leaf_mask(cfg: LMConfig):
 
 
 def _block_decode(h, p, cache, kind, cfg, qcfg, pos, enc_out=None,
-                  page_table=None, page_size: int = 0):
+                  page_table=None, page_size: int = 0, token_mask=None):
+    """One block of a decode step: (h, new cache entry, routing counters
+    or None)."""
     if kind in ("attn", "dense_attn", "dec_attn"):
         hn = apply_norm(p["ln1"], h, qcfg, cfg.norm)
         paged = (page_table is not None and page_size > 0
@@ -673,34 +730,27 @@ def _block_decode(h, p, cache, kind, cfg, qcfg, pos, enc_out=None,
                                   causal=False, q_chunk=1,
                                   kv_chunk=cfg.kv_chunk))
         hn2 = apply_norm(p["ln2"], h, qcfg, cfg.norm)
-        if "moe" in p:
-            B = h.shape[0]
-            y, _ = moe_apply(p["moe"], hn2.reshape(B, -1), qcfg,
-                             top_k=cfg.top_k, act=cfg.act,
-                             capacity_factor=4.0)
-            y = y.reshape(B, 1, -1)
-            if "shared" in p:
-                y = y + mlp_apply(p["shared"], hn2, qcfg, cfg.act)
-        else:
-            y = mlp_apply(p["mlp"], hn2, qcfg, cfg.act)
-        return h + y, new_cache
+        y, metrics = _ffn(p, hn2, cfg, qcfg, serving=True,
+                          token_mask=None if token_mask is None
+                          else token_mask[:, None])
+        return h + y, new_cache, _routing(metrics)
     if kind == "rec":
         a, new_cache = rec_block_decode(
             p["rec"], apply_norm(p["ln1"], h, qcfg, cfg.norm), cache, qcfg)
         h = h + a
         h = h + mlp_apply(p["mlp"], apply_norm(p["ln2"], h, qcfg, cfg.norm),
                           qcfg, cfg.act)
-        return h, new_cache
+        return h, new_cache, None
     if kind == "mlstm":
         a, new_cache = mlstm_decode(p["cell"],
                                     apply_norm(p["ln"], h, qcfg, cfg.norm),
                                     cache, qcfg, cfg.n_heads)
-        return h + a, new_cache
+        return h + a, new_cache, None
     if kind == "slstm":
         a, new_cache = slstm_decode(p["cell"],
                                     apply_norm(p["ln"], h, qcfg, cfg.norm),
                                     cache, qcfg, cfg.n_heads)
-        return h + a, new_cache
+        return h + a, new_cache, None
     raise ValueError(kind)
 
 
@@ -717,21 +767,36 @@ def lm_decode_step(params, cache, tok, pos, cfg: LMConfig,
     instead of per-row slabs; slab-fallback leaves behave as before.
 
     Returns (logits (B, vocab), new_cache)."""
+    logits, new_caches, _ = lm_decode_step_routed(
+        params, cache, tok, pos, cfg, qcfg, enc_out, page_table, page_size)
+    return logits, new_caches
+
+
+def lm_decode_step_routed(params, cache, tok, pos, cfg: LMConfig,
+                          qcfg: QuantConfig, enc_out=None, page_table=None,
+                          page_size: int = 0, token_mask=None):
+    """``lm_decode_step`` that also returns the step's routing counters:
+    (logits, new_cache, routing), ``routing`` None for a model without
+    experts, else {"held_tokens": (moe_layers, held) int32 tokens routed
+    to each held expert, "dropped": int32}.  ``token_mask`` ((B,) bool)
+    keeps rows out of the routing (a serving batch's dead rows)."""
     pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (tok.shape[0],))
     h = shard_act(embed_lookup(params["embed"], tok))
     plan = _decoder_plan(cfg)
-    new_caches = []
+    new_caches, routing = [], []
     for (pattern, n_rep), gp, gc in zip(plan, params["blocks"], cache):
         def body(h, xs, pattern=pattern):
             lp, lc = xs
-            new_lc = {}
+            new_lc, rs = {}, []
             for j, kind in enumerate(pattern):
-                h, nc = _block_decode(h, lp[f"b{j}"], lc[f"b{j}"], kind, cfg,
-                                      qcfg, pos, enc_out,
-                                      page_table=page_table,
-                                      page_size=page_size)
+                h, nc, r = _block_decode(h, lp[f"b{j}"], lc[f"b{j}"], kind,
+                                         cfg, qcfg, pos, enc_out,
+                                         page_table=page_table,
+                                         page_size=page_size,
+                                         token_mask=token_mask)
                 new_lc[f"b{j}"] = nc
-            return h, new_lc
+                rs.append(r)
+            return h, new_lc, rs
 
         if cfg.scan_layers and n_rep > 1:
             # The stacked cache rides in the carry, each layer's entry
@@ -743,25 +808,27 @@ def lm_decode_step(params, cache, tok, pos, cfg: LMConfig,
                 lc = jax.tree.map(
                     lambda a: jax.lax.dynamic_index_in_dim(a, r, 0, False),
                     gc)
-                h, nc = body(h, (lp, lc))
+                h, nc, rs = body(h, (lp, lc))
                 gc = jax.tree.map(
                     lambda a, n: jax.lax.dynamic_update_index_in_dim(
                         a, n.astype(a.dtype), r, 0), gc, nc)
-                return (h, gc, r + 1), None
+                return (h, gc, r + 1), rs
 
-            (h, new_gc, _), _ = jax.lax.scan(layer, (h, gc, 0), gp)
+            (h, new_gc, _), rs = jax.lax.scan(layer, (h, gc, 0), gp)
+            routing.append(_stack_routing(rs, stacked=True))
         else:
             new_gc_list = []
             for r in range(n_rep):
                 lp = jax.tree.map(lambda a, r=r: a[r], gp)
                 lc = jax.tree.map(lambda a, r=r: a[r], gc)
-                h, nc = body(h, (lp, lc))
+                h, nc, rs = body(h, (lp, lc))
                 new_gc_list.append(nc)
+                routing.append(_stack_routing(rs, stacked=False))
             new_gc = jax.tree.map(lambda *xs: jnp.stack(xs), *new_gc_list)
         new_caches.append(new_gc)
     h = apply_norm(params["final_ln"], h, qcfg, cfg.norm)
     logits = _head_matmul(params, h[:, 0], cfg, qcfg)
-    return logits, new_caches
+    return logits, new_caches, _join_routing(routing)
 
 
 # --------------------------------------------------------------------------
@@ -794,19 +861,7 @@ def _block_prefill(h, p, kind, cfg: LMConfig, qcfg: QuantConfig, positions,
                 rope_theta=cfg.rope_theta)
         h = h + a
         hn2 = apply_norm(p["ln2"], h, qcfg, cfg.norm)
-        if "moe" in p:
-            B, T, D = hn2.shape
-            # Serving capacity matches _block_decode (generous 4.0): the
-            # training capacity would drop prompt tokens that per-step
-            # decode never drops.
-            y, _ = moe_apply(p["moe"], hn2.reshape(B * T, D), qcfg,
-                             top_k=cfg.top_k, act=cfg.act,
-                             capacity_factor=4.0)
-            y = y.reshape(B, T, D)
-            if "shared" in p:
-                y = y + mlp_apply(p["shared"], hn2, qcfg, cfg.act)
-        else:
-            y = mlp_apply(p["mlp"], hn2, qcfg, cfg.act)
+        y, _ = _ffn(p, hn2, cfg, qcfg, serving=True)
         return h + y, nc
     if kind == "rec":
         a, nc = rec_block_prefill(p["rec"],
@@ -847,12 +902,9 @@ def lm_prefill(params, tokens, cfg: LMConfig, qcfg: QuantConfig,
     Returns (logits (B, vocab), cache) with ``cache`` exactly matching the
     ``init_cache`` tree, ready for ``lm_decode_step`` at position T.
 
-    MoE caveat: routing capacity here is bounded over the whole batched
-    prompt (at the decode path's generous 4.0 factor), while token-stepped
-    warmup routes one token per step and never hits capacity — under
-    extreme (>4x mean) expert imbalance the two can drop different tokens,
-    so MoE parity is routing-tolerance rather than quantization-tight (and
-    the engine never pads MoE prompts, see ServeEngine.pad_safe).
+    MoE routing is dropless here as in decode, so a batched prompt routes
+    each token as token-stepped warmup would (the engine still never pads
+    MoE prompts, see ServeEngine.pad_safe: padded tokens would route).
     """
     if not prefill_supported(cfg):
         raise NotImplementedError(
@@ -894,13 +946,12 @@ def lm_prefill(params, tokens, cfg: LMConfig, qcfg: QuantConfig,
 # chunked prefill (serving)
 # --------------------------------------------------------------------------
 def chunk_supported(cfg: LMConfig) -> bool:
-    """Whether ``lm_prefill_chunk`` covers this config: a pure global-
-    attention decoder stack.  Windowed/ring, recurrent, MLA, and MoE
-    configs prefill whole (``lm_prefill``) and are pagified afterwards —
-    their prefix state is not an append-only K/V sequence (ring slots,
-    RNN state, latent re-expansion, batch-level routing)."""
-    return (prefill_supported(cfg) and not cfg.mla and cfg.window == 0
-            and cfg.n_experts == 0 and cfg.d_rnn == 0
+    """Whether ``lm_prefill_chunk`` covers this config: a global-attention
+    decoder stack, GQA or MLA, with MLPs or experts (and its leading dense
+    layers).  Windowed/ring and recurrent configs prefill whole
+    (``lm_prefill``) and are pagified afterwards — their prefix state is
+    not an append-only sequence (ring slots, RNN state)."""
+    return (prefill_supported(cfg) and cfg.window == 0 and cfg.d_rnn == 0
             and set(cfg.block_pattern) <= {"attn"})
 
 
@@ -910,51 +961,69 @@ def lm_prefill_chunk(params, tokens, prior, start: int, cfg: LMConfig,
     """One chunk of a continuous prefill: forward ``tokens`` (B, C) at
     absolute positions ``start .. start+C-1`` attending the already-written
     prefix through ``prior`` — a cache-shaped tree whose attention leaves
-    hold the gathered (n_rep, B, start, Hkv, d) prefix K/V (empty leading
-    chunks pass start=0 arrays).
+    hold the gathered (n_rep, B, start, Hkv, d) prefix K/V, or the
+    (n_rep, B, start, ·) MLA latents (empty leading chunks pass start=0
+    arrays).
 
     Returns (logits (B, vocab) at ``logit_positions`` (default C-1),
-    chunk_kv) where chunk_kv mirrors the cache structure with the chunk's
-    (n_rep, B, C, Hkv, d) K/V for the caller to write into fresh pages.
-    ``kv_mask`` ((B, C) bool) zeroes padded tail K/V so a fixed chunk
-    shape can carry a shorter final chunk."""
+    chunk_kv, routing) where chunk_kv mirrors the cache structure with the
+    chunk's (n_rep, B, C, Hkv, d) K/V (or (n_rep, B, C, ·) latents) for
+    the caller to write into fresh pages, and ``routing`` is the chunk's
+    routing counters as ``lm_decode_step_routed`` gives them (None
+    without experts).  ``kv_mask`` ((B, C) bool) zeroes padded tail K/V
+    and keeps those positions out of the routing, so a fixed chunk shape
+    can carry a shorter final chunk."""
     if not chunk_supported(cfg):
         raise NotImplementedError(
-            "chunked prefill covers pure global-attention decoder stacks; "
+            "chunked prefill covers global-attention decoder stacks; "
             "other configs prefill whole and pagify")
     B, C = tokens.shape
     h = shard_act(embed_lookup(params["embed"], tokens))
     positions = jnp.broadcast_to(jnp.arange(start, start + C)[None], (B, C))
     plan = _decoder_plan(cfg)
-    chunk_caches = []
+    chunk_caches, routing = [], []
     for (pattern, n_rep), gp, gc in zip(plan, params["blocks"], prior):
         def body(h, xs, pattern=pattern):
             lp, lc = xs
-            nc = {}
+            nc, rs = {}, []
             for j, kind in enumerate(pattern):
-                hn = apply_norm(lp[f"b{j}"]["ln1"], h, qcfg, cfg.norm)
+                p, c = lp[f"b{j}"], lc[f"b{j}"]
+                hn = apply_norm(p["ln1"], h, qcfg, cfg.norm)
                 spec = cfg.attn_spec(kind).with_offset(start)
-                a, ck, cv = attention_prefill_chunk(
-                    lp[f"b{j}"]["attn"], hn, lc[f"b{j}"]["k"],
-                    lc[f"b{j}"]["v"], qcfg=qcfg, n_heads=cfg.n_heads,
-                    n_kv=cfg.n_kv_heads, d_head=cfg.d_head,
-                    positions=positions, spec=spec, kv_mask=kv_mask,
-                    rope_theta=cfg.rope_theta)
+                if cfg.mla:
+                    a, ckv, kr = mla_prefill_chunk(
+                        p["attn"], hn, c["ckv"], c["kr"], qcfg=qcfg,
+                        n_heads=cfg.n_heads, nope=cfg.nope_dim,
+                        rope_dim=cfg.rope_dim, v_head=cfg.v_head,
+                        positions=positions, spec=spec, kv_mask=kv_mask,
+                        rope_theta=cfg.rope_theta)
+                    nc[f"b{j}"] = {"ckv": ckv, "kr": kr}
+                else:
+                    a, ck, cv = attention_prefill_chunk(
+                        p["attn"], hn, c["k"], c["v"], qcfg=qcfg,
+                        n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                        d_head=cfg.d_head, positions=positions, spec=spec,
+                        kv_mask=kv_mask, rope_theta=cfg.rope_theta)
+                    nc[f"b{j}"] = {"k": ck, "v": cv}
                 h = h + a
-                hn2 = apply_norm(lp[f"b{j}"]["ln2"], h, qcfg, cfg.norm)
-                h = h + mlp_apply(lp[f"b{j}"]["mlp"], hn2, qcfg, cfg.act)
-                nc[f"b{j}"] = {"k": ck, "v": cv}
-            return h, nc
+                hn2 = apply_norm(p["ln2"], h, qcfg, cfg.norm)
+                y, metrics = _ffn(p, hn2, cfg, qcfg, serving=True,
+                                  token_mask=kv_mask)
+                h = h + y
+                rs.append(_routing(metrics))
+            return h, (nc, rs)
 
         if cfg.scan_layers and n_rep > 1:
-            h, cc = jax.lax.scan(body, h, (gp, gc))
+            h, (cc, rs) = jax.lax.scan(body, h, (gp, gc))
+            routing.append(_stack_routing(rs, stacked=True))
         else:
             cc_list = []
             for r in range(n_rep):
                 lp = jax.tree.map(lambda a, r=r: a[r], gp)
                 lc = jax.tree.map(lambda a, r=r: a[r], gc)
-                h, c = body(h, (lp, lc))
+                h, (c, rs) = body(h, (lp, lc))
                 cc_list.append(c)
+                routing.append(_stack_routing(rs, stacked=False))
             cc = jax.tree.map(lambda *xs: jnp.stack(xs), *cc_list)
         chunk_caches.append(cc)
     h = apply_norm(params["final_ln"], h, qcfg, cfg.norm)
@@ -962,4 +1031,4 @@ def lm_prefill_chunk(params, tokens, prior, start: int, cfg: LMConfig,
         logit_positions = jnp.full((B,), C - 1, jnp.int32)
     h_last = h[jnp.arange(B), logit_positions]          # (B, D)
     logits = _head_matmul(params, h_last, cfg, qcfg)
-    return logits, chunk_caches
+    return logits, chunk_caches, _join_routing(routing)

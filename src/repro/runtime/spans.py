@@ -9,7 +9,9 @@ phases, begun anew by :meth:`Spans.step`) and to ``totals``.  With no
 trace active the annotation costs about a microsecond.
 
 A phase whose name ends in ``.wait`` is, by convention, the host blocked
-on the device; no other phase takes that suffix.
+on the device; no other phase takes that suffix.  ``counters`` holds the
+loop's counts beside its times (e.g. ``moe.held_tokens``), added to by
+their owner.
 """
 from __future__ import annotations
 
@@ -30,6 +32,7 @@ class Spans:
         self.prefix = prefix
         self.last_step: Dict[str, float] = {}
         self.totals: Dict[str, float] = defaultdict(float)
+        self.counters: Dict[str, float] = defaultdict(float)
 
     @contextlib.contextmanager
     def __call__(self, name: str, **args: int) -> Iterator[None]:

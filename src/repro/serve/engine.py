@@ -38,6 +38,13 @@ Both engines serve the dense projections MX-packed at rest
 (``models.pack_serving_weights``) wherever the preset quantizes weights;
 ``stats()`` reports ``packed_weight_share`` and ``packed_weight_bytes``,
 and the journal's ``pack_weights`` record holds both from construction.
+
+For a model with experts the paged engine's decode step and prefill chunks
+also return their routing counters, which the host reads with the sampled
+tokens: ``decode_routing`` holds the last decode step's (moe_layers, held)
+tokens routed to each expert held here, and the counters
+``moe.held_tokens`` and ``moe.dropped`` (decode and prefill together) sit
+in ``spans.counters`` and ``stats()``.
 """
 from __future__ import annotations
 
@@ -53,7 +60,8 @@ import numpy as np
 from repro.core import QuantConfig
 from repro.runtime import Journal, MemoryLedger, SegmentFn, Spans
 from repro.models import (LMConfig, block_plan, chunk_supported, init_cache,
-                          init_cache_paged, lm_decode_step, lm_prefill,
+                          init_cache_paged, lm_decode_step,
+                          lm_decode_step_routed, lm_prefill,
                           lm_prefill_chunk, pack_serving_weights,
                           paged_leaf_mask, prefill_supported)
 from .pages import (PageAllocator, gather_prior, prefix_chain,
@@ -108,13 +116,17 @@ def _serve_step_paged(params, cache, tok, pos, page_table, cfg: LMConfig,
                       n_gen, any_sampled: bool, any_top_k: bool):
     """Paged engine step: eligible attention layers address (N, ps, ·)
     pools through the (B, P) page table; slab-fallback leaves (ring /
-    recurrent state) behave exactly as in ``_serve_step``."""
-    logits, cache = lm_decode_step(params, cache, tok, pos, cfg, qcfg,
-                                   page_table=page_table,
-                                   page_size=page_size)
+    recurrent state) behave exactly as in ``_serve_step``.  Returns
+    (next tokens, cache, routing counters or None).  The engine blanks
+    the table of every row that decodes for no request, so rows whose
+    first page is unmapped stay out of the routing."""
+    live = page_table[:, 0] >= 0 if cfg.n_experts else None
+    logits, cache, routing = lm_decode_step_routed(
+        params, cache, tok, pos, cfg, qcfg, page_table=page_table,
+        page_size=page_size, token_mask=live)
     nxt = sample_tokens(logits, temp, top_k, seeds, n_gen,
                         any_sampled, any_top_k)
-    return nxt, cache
+    return nxt, cache, routing
 
 
 @partial(SegmentFn, donate_argnums=(0,))
@@ -191,6 +203,7 @@ class ServeEngine:
         self.ledger.account("cache", self.cache)
         # host phases of each step, on the profiler's clock (serve.*)
         self.spans = Spans("serve")
+        self.decode_routing: Optional[np.ndarray] = None
         self.finished: Dict[int, Request] = {}
         self._next_rid = 0
         self._decode_steps = 0
@@ -319,6 +332,11 @@ class ServeEngine:
                                       seeds, n_gen, any_sampled, any_top_k)
         return nxt
 
+    def _fetch(self, nxt) -> np.ndarray:
+        """The decode step's sampled tokens on the host (where the host
+        blocks on the step)."""
+        return np.asarray(nxt)
+
     def _post_finish(self, finished: List[Request]) -> None:
         """Hook after requests finish (paged: release their pages)."""
 
@@ -340,8 +358,12 @@ class ServeEngine:
                 finished.extend(self._pre_decode())
             n_live = self.sched.n_active
             if n_live:
+                # a step's routing reaches the host with its tokens, after
+                # its own span: the span carries the step before's
+                held = {} if self.decode_routing is None else {
+                    "held_tokens": int(self.decode_routing.sum())}
                 with self.spans("decode", live=n_live,
-                                dead=self.sched.max_batch - n_live):
+                                dead=self.sched.max_batch - n_live, **held):
                     tok, pos, temp, top_k, seeds, n_gen = \
                         self.sched.batch_arrays()
                     nxt = self._decode_batch(
@@ -349,7 +371,7 @@ class ServeEngine:
                         bool((self.sched.temp > 0).any()),
                         bool((self.sched.top_k > 0).any()))
                 with self.spans("decode.wait"):
-                    nxt = np.asarray(nxt)
+                    nxt = self._fetch(nxt)
                 self._decode_steps += 1
                 self._decode_tokens += n_live
             with self.spans("finish"):
@@ -464,6 +486,10 @@ class PagedServeEngine(ServeEngine):
         self._reserved: Set[int] = set()
         self._ready: List[Tuple[_PrefillJob, Any]] = []
         self._preemptions = 0
+        # routing counters still on the device: the prefill chunks' and
+        # the last decode step's, read with the decode step's tokens
+        self._chunk_routing: List[Any] = []
+        self._step_routing = None
         # Flattened-cache metadata: the page pools are a leaf *subset* of
         # the cache tree (slab fallbacks interleave), so the device page
         # helpers map over explicit leaf tuples and the engine reassembles.
@@ -618,9 +644,11 @@ class PagedServeEngine(ServeEngine):
         prior = jax.tree_util.tree_unflatten(
             self._treedef, list(gather_prior(pools, jnp.asarray(prior_ids),
                                              self._rules)))
-        logits, chunk_kv = _prefill_chunk(
+        logits, chunk_kv, routing = _prefill_chunk(
             self.params, jnp.asarray(toks)[None], prior, start, self.cfg, qc,
             jnp.asarray([real - 1], jnp.int32), kv_mask)
+        if routing is not None:
+            self._chunk_routing.append(routing)
         ids = self._row_ids(job.pages, start // ps, C // ps)
         n_sealed = max(0, min(T // ps - start // ps, C // ps))
         pools = write_chunk_pages(
@@ -772,11 +800,28 @@ class PagedServeEngine(ServeEngine):
         live = np.fromiter((r is not None for r in self.sched.slots),
                            bool, self.sched.max_batch)
         pt = np.where(live[:, None], self.page_table, -1).astype(np.int32)
-        nxt, self.cache = _serve_step_paged(
+        nxt, self.cache, self._step_routing = _serve_step_paged(
             self.params, self.cache, tok, pos, jnp.asarray(pt), self.cfg,
             self.qcfg, self.page_size, temp, top_k, seeds, n_gen,
             any_sampled, any_top_k)
         return nxt
+
+    def _fetch(self, nxt) -> np.ndarray:
+        """The sampled tokens, and with them the routing counters of this
+        step and of the prefill chunks dispatched before it (done by now,
+        so reading them adds no wait)."""
+        nxt, step, chunks = jax.device_get(
+            (nxt, self._step_routing, self._chunk_routing))
+        self._step_routing, self._chunk_routing = None, []
+        self._count_routing(chunks + ([step] if step is not None else []))
+        self.decode_routing = None if step is None else step["held_tokens"]
+        return np.asarray(nxt)
+
+    def _count_routing(self, routings) -> None:
+        for r in routings:
+            self.spans.counters["moe.held_tokens"] += float(
+                r["held_tokens"].sum())
+            self.spans.counters["moe.dropped"] += float(r["dropped"])
 
     @property
     def has_work(self) -> bool:
@@ -786,6 +831,12 @@ class PagedServeEngine(ServeEngine):
     # ---- reporting ---------------------------------------------------------
     def stats(self) -> Dict[str, float]:
         out = super().stats()
+        if self.cfg.n_experts:
+            chunks = jax.device_get(self._chunk_routing)
+            self._chunk_routing = []
+            self._count_routing(chunks)
+            out.update({k: self.spans.counters[k]
+                        for k in ("moe.held_tokens", "moe.dropped")})
         out.update({
             "n_pages": float(self.n_pages),
             "page_size": float(self.page_size),

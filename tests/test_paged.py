@@ -71,8 +71,8 @@ def _results(eng):
                                   "deepseek-v2-236b"])
 def test_paged_vs_slab_greedy_parity(arch, prec):
     """qwen2: chunked prefill + fully paged pools; recurrentgemma: ring +
-    recurrent state = pure slab fallback (0 paged leaves); deepseek MLA:
-    whole-prompt prefill pagified into raw-latent pools.  All three must
+    recurrent state = pure slab fallback (0 paged leaves); deepseek MLA
+    with experts: chunked prefill into raw-latent pools.  All three must
     match the slab engine token-for-token, greedy and sampled rows alike
     (a sampled row's stream is a pure function of bitwise-equal logits)."""
     cfg, params = _setup(arch)

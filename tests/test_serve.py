@@ -85,8 +85,8 @@ def test_fused_prefill_matches_token_stepped(arch, prec):
 def test_fused_prefill_windowed_and_recurrent_parity(arch):
     """Ring-buffer attention, recurrent/xLSTM state, and MoE stacks built
     in one fused pass match token-stepped warmup (scan-order / routing
-    tolerance — batched-prompt MoE capacity can differ from per-token
-    routing only under >4x expert imbalance)."""
+    tolerance: both route dropless, but expanded prefill and absorbed
+    decode round apart, which can flip a near-tie expert choice)."""
     try:
         cfg, params, toks = _setup(arch)
     except KeyError:

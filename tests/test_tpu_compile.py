@@ -6,7 +6,8 @@ reshape that splits the lane dim, a block that breaks the (8, 128) tiling
 rule, a tile over the scoped VMEM limit) it would refuse on the chip.
 Interpret-mode tests cannot see any of that, so these compiles guard the
 kernels at olmo-paper widths (d_model 512, d_ff 2048, vocab 32000,
-d_head 64, context 512), and the packed serving GEMM at starcoder2-3b's.
+d_head 64, context 512), the packed serving GEMM at starcoder2-3b's, and
+the held experts' GEMMs at Moonlight-16B-A3B's.
 Nothing runs: a compile that passes is not a
 chip run.
 
@@ -87,6 +88,28 @@ def test_mx_matmul_packed_compiles(one_chip, m, k, n):
                                          None),
         ((m, k), BF16), ((k, n), jnp.uint8), ((k // 32, n), jnp.uint8))
     assert "%mx_matmul_pallas" in text
+
+
+@pytest.mark.parametrize("m", [64, 256])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)], ids=str)
+def test_held_expert_bmm_compiles_under_its_own_label(one_chip, monkeypatch,
+                                                      m, k, n):
+    """Moonlight-16B-A3B's eight held experts (up/gate 2048 x 1408, down
+    1408 x 2048) on a dropless 64-row decode step and a 256-row prefill
+    chunk, bf16 weights quantized on load, as ``mx_contract(kind="bmm")``
+    runs them: the kernel reads as ``mx_expert_matmul_pallas`` and not as
+    the dense projections' ``mx_matmul_pallas``, which the benchmark's
+    expert and dense roofline metrics tell apart."""
+    import repro.kernels.ops as ops
+    from repro.core import mx_contract, preset, use_fused_gemms
+    monkeypatch.setattr(ops, "_use_interpret", lambda: False)
+    qcfg = preset("e4m3_bf16act")
+    with use_fused_gemms(True):
+        text = _compile(one_chip,
+                        lambda x, w: mx_contract(x, w, qcfg, kind="bmm"),
+                        ((8, m, k), BF16), ((8, k, n), BF16))
+    assert "%mx_expert_matmul_pallas" in text
+    assert "%mx_matmul_pallas" not in text
 
 
 @pytest.mark.parametrize("m,k,n", [(TOKENS, D, FF), (TOKENS, D, VOCAB)],
